@@ -295,4 +295,6 @@ def run(smoke: bool = False):
 if __name__ == "__main__":
     from benchmarks.common import trace_from_argv
     trace_from_argv()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     run(smoke="--smoke" in sys.argv)

@@ -81,7 +81,7 @@ def _build_checkpoint(d: str, n_float: int, n_quant: int, mb: int,
     return m.total_bytes
 
 
-def _restore_child(q, d: str, streaming: bool, inflight: int) -> None:
+def _restore_child(d: str, streaming: bool, inflight: int) -> dict:
     """Fresh-process restore: peak RSS is this run's, not the parent's."""
     import resource
 
@@ -102,23 +102,33 @@ def _restore_child(q, d: str, streaming: bool, inflight: int) -> None:
     for leaf in flat:
         if hasattr(leaf, "shape"):
             digest = zlib.crc32(np.ascontiguousarray(leaf), digest)
-    q.put({"wall_s": wall, "digest": digest & 0xFFFFFFFF,
-           "mode": m.mode,
-           "read_s": m.read_seconds,
-           "read_stall_s": m.read_stall_seconds,
-           "decode_s": m.decode_seconds,
-           "assemble_s": m.assemble_seconds,
-           "stage_sum_s": m.stage_seconds,
-           "overlap_s": m.overlap_seconds,
-           "peak_staged_bytes": m.peak_staged_bytes,
-           "peak_rss_bytes": resource.getrusage(
-               resource.RUSAGE_SELF).ru_maxrss * 1024})
+    return {"wall_s": wall, "digest": digest & 0xFFFFFFFF,
+            "mode": m.mode,
+            "read_s": m.read_seconds,
+            "read_stall_s": m.read_stall_seconds,
+            "decode_s": m.decode_seconds,
+            "assemble_s": m.assemble_seconds,
+            "stage_sum_s": m.stage_seconds,
+            "overlap_s": m.overlap_seconds,
+            "peak_staged_bytes": m.peak_staged_bytes,
+            "peak_rss_bytes": resource.getrusage(
+                resource.RUSAGE_SELF).ru_maxrss * 1024}
 
 
-def _restore_once(d: str, streaming: bool, inflight: int) -> dict:
+def _child_entry(q, fn, args) -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+    q.put(fn(*args))
+
+
+def _in_child(fn, *args):
+    """Run ``fn(*args)`` in a fresh spawned process and return its result.
+
+    Every step that touches JAX runs in such a child, one at a time, so
+    this parent never holds an accelerator a child needs."""
     ctx = mp.get_context("spawn")
     q = ctx.Queue()
-    p = ctx.Process(target=_restore_child, args=(q, d, streaming, inflight))
+    p = ctx.Process(target=_child_entry, args=(q, fn, args))
     p.start()
     deadline = time.monotonic() + 1200
     out = None
@@ -133,11 +143,10 @@ def _restore_once(d: str, streaming: bool, inflight: int) -> dict:
                 except queue.Empty:
                     pass           # crashed/OOM-killed: its stderr has why
                 raise RuntimeError(
-                    f"restore child (streaming={streaming}) died with "
-                    f"exitcode {p.exitcode}")
+                    f"{fn.__name__} child died with exitcode {p.exitcode}")
             if time.monotonic() > deadline:
                 p.kill()
-                raise TimeoutError("restore child exceeded 1200s")
+                raise TimeoutError(f"{fn.__name__} child exceeded 1200s")
     p.join()
     return out
 
@@ -149,7 +158,7 @@ def run_mode_comparison(rep: Report, smoke: bool = False) -> dict:
     reps = 3
 
     d = fresh_dir("restore_modes")
-    total = _build_checkpoint(d, n_float, n_quant, mb, inflight)
+    total = _in_child(_build_checkpoint, d, n_float, n_quant, mb, inflight)
 
     out = {"checkpoint_bytes": total, "inflight_bytes": inflight,
            "reps": reps, "modes": {}}
@@ -158,7 +167,7 @@ def run_mode_comparison(rep: Report, smoke: bool = False) -> dict:
         for _ in range(reps):
             os.sync()                  # writeback from the previous run
             drop_caches()              # cold reads: the restore we model
-            r = _restore_once(d, streaming, inflight)
+            r = _in_child(_restore_child, d, streaming, inflight)
             if best is None or r["wall_s"] < best["wall_s"]:
                 best = r
         out["modes"][name] = {k: (round(v, 6) if isinstance(v, float) else v)

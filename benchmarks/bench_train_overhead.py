@@ -142,5 +142,7 @@ def run(full_scale: bool = False, quick: bool = False, smoke: bool = False):
 if __name__ == "__main__":
     from benchmarks.common import trace_from_argv
     trace_from_argv()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     run(full_scale="--full-scale" in sys.argv, quick="--quick" in sys.argv,
         smoke="--smoke" in sys.argv)

@@ -40,6 +40,8 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks.common import RESULTS_DIR
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     only = {m.strip() for m in args.only.split(",") if m.strip()}
     csv_rows = [("name", "us_per_call", "derived")]
     for name in MODULES:

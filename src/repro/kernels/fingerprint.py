@@ -50,7 +50,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from .quantize import LANE_COLS, quant_rows
+from ..core import trace
+from .quantize import (LANE_COLS, SLAB_ROWS, for_slabs, quant_rows,
+                       quantize_blocks, slab_rows)
 
 DIGEST_KIND = "fp128"
 LANE_BYTES = 4
@@ -170,25 +172,23 @@ def lanes_u32(flat):
     Built arithmetically from same-width bitcasts: XLA's
     ``bitcast_convert_type`` is only byte-order-defined at equal widths,
     so wider lanes are assembled as ``b0 | b1<<8 | ...`` — bit-identical
-    to the host's ``view(np.uint32)`` on little-endian layouts."""
+    to the host's ``view(np.uint32)`` on little-endian layouts. The bytes
+    of a lane are taken with strided slices, not a reshape to a minor dim
+    of 2 or 4, which a TPU pads to 128 lanes (64x the memory)."""
     isz = np.dtype(flat.dtype).itemsize
     if isz == 4:
         return jax.lax.bitcast_convert_type(flat, jnp.uint32)
-    if isz == 2:
-        u = jax.lax.bitcast_convert_type(flat, jnp.uint16) \
-            .astype(jnp.uint32)
-        if u.shape[0] % 2:
-            u = jnp.pad(u, (0, 1))
-        u = u.reshape(-1, 2)
-        return u[:, 0] | (u[:, 1] << 16)
-    if isz == 1:
-        u = jax.lax.bitcast_convert_type(flat, jnp.uint8) \
-            .astype(jnp.uint32)
-        if u.shape[0] % 4:
-            u = jnp.pad(u, (0, 4 - u.shape[0] % 4))
-        u = u.reshape(-1, 4)
-        return u[:, 0] | (u[:, 1] << 8) | (u[:, 2] << 16) | (u[:, 3] << 24)
-    raise ValueError(f"unsupported itemsize {isz} for device fingerprint")
+    if isz not in (1, 2):
+        raise ValueError(f"unsupported itemsize {isz} for device fingerprint")
+    per = LANE_BYTES // isz
+    u = jax.lax.bitcast_convert_type(flat, (jnp.uint8, jnp.uint16)[isz - 1]) \
+        .astype(jnp.uint32)
+    if u.shape[0] % per:
+        u = jnp.pad(u, (0, per - u.shape[0] % per))
+    out = u[0::per]
+    for j in range(1, per):
+        out = out | (u[j::per] << (8 * isz * j))
+    return out
 
 
 def _digest_lane_stream(lanes, nbytes: int, chunk_bytes: int):
@@ -242,55 +242,112 @@ def fingerprint_digests(flat, chunk_bytes: int) -> np.ndarray:
 
 
 # ------------------------------------------------------------- Pallas kernels
-def _fp_kernel(lanes_ref, len_ref, d_ref):
-    lanes = lanes_ref[...]                                 # (1, CL) uint32
-    pos = jax.lax.broadcasted_iota(jnp.uint32, lanes.shape, 1) \
-        + jnp.uint32(1)
-    n = len_ref[0, 0]
-    acc = []
-    for s, ln in zip(_SEEDS, _LEN):
-        w = _fmix32_jnp(pos ^ jnp.uint32(s)) | jnp.uint32(1)
-        acc.append(jnp.sum(lanes * w, dtype=jnp.uint32)
-                   + n * jnp.uint32(ln))
-    d_ref[0, :] = jnp.stack(acc)
+# TPU tiling: every block's last two dims are (8, 128)-aligned or whole, no
+# reduction runs on unsigned integers, and no scalar is bitcast. Weights and
+# products are formed in uint32, bitcast to int32 as vectors and summed in
+# int32: wrapping int32 addition has the same bits as uint32 addition mod
+# 2^32. The length fold and the bitcast back to uint32 run outside the
+# kernel, in ``_finish_digests``.
+FP_ROWS = 8          # chunks per fingerprint_chunks grid step
+FP_SLAB = 512        # lanes per inner iteration
+
+
+def _weights(pos, seed: int):
+    return _fmix32_jnp(pos ^ jnp.uint32(seed)) | jnp.uint32(1)
+
+
+def _i32(x):
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _fold_rows(x):
+    """(r, c) -> (8, c) by adding aligned 8-row groups (r % 8 == 0)."""
+    if x.shape[0] % 8:
+        return x
+    out = x[0:8]
+    for r in range(8, x.shape[0], 8):
+        out = out + x[r:r + 8]
+    return out
+
+
+def _put_columns(cols):
+    """Four (r, 1) int32 columns -> one (r, 4) block, no lane concat."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (cols[0].shape[0], 4), 1)
+    out = jnp.zeros(lane.shape, jnp.int32)
+    for k, c in enumerate(cols):
+        out = jnp.where(lane == k, c, out)
+    return out
+
+
+def _finish_digests(acc, lengths):
+    """Kernel int32 sums (n, 4) + byte lengths (n, 1) -> uint32 digests."""
+    return (jax.lax.bitcast_convert_type(acc, jnp.uint32)
+            + lengths.reshape(-1, 1).astype(jnp.uint32)
+            * jnp.asarray(_LEN, jnp.uint32))
+
+
+def _fp_kernel(lanes_ref, d_ref):
+    rows, cl = lanes_ref.shape                     # rows chunks of cl lanes
+    slab = FP_SLAB if cl % FP_SLAB == 0 else cl
+
+    def body(j, accs):
+        c0 = pl.multiple_of(j * slab, slab)
+        v = lanes_ref[:, pl.ds(c0, slab)]
+        pos = jax.lax.broadcasted_iota(jnp.uint32, (1, slab), 1) \
+            + (c0 + 1).astype(jnp.uint32)
+        return tuple(a + _i32(v * _weights(pos, s))
+                     for a, s in zip(accs, _SEEDS))
+
+    zero = jnp.zeros((rows, slab), jnp.int32)
+    accs = jax.lax.fori_loop(0, cl // slab, body, (zero,) * 4)
+    d_ref[...] = _put_columns([jnp.sum(a, axis=1, keepdims=True)
+                               for a in accs])
 
 
 def fingerprint_chunks(lanes, lengths, *, interpret: bool = False):
     """lanes: (n_chunks, CL) uint32; lengths: (n_chunks, 1) uint32 byte
     length of each chunk's digest domain. Returns (n_chunks, 4) uint32.
-    One chunk per grid step: a 256 KiB chunk is a 64Ki-lane block
-    (256 KiB of VMEM) with weights regenerated from iota in-register."""
+    FP_ROWS chunks per grid step: eight 256 KiB chunks are a 2 MiB block
+    whose last dim is the whole lane axis; weights are regenerated from
+    iota one FP_SLAB-lane slab at a time."""
     nc, cl = lanes.shape
-    return pl.pallas_call(
+    rows = min(nc, FP_ROWS)
+    acc = pl.pallas_call(
         _fp_kernel,
-        grid=(nc,),
-        in_specs=[pl.BlockSpec((1, cl), lambda i: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 4), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nc, 4), jnp.uint32),
+        grid=(pl.cdiv(nc, rows),),
+        in_specs=[pl.BlockSpec((rows, cl), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((rows, 4), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nc, 4), jnp.int32),
         interpret=interpret,
-    )(lanes, lengths)
+    )(lanes)
+    return _finish_digests(acc, lengths)
 
 
-def _quant_fp_kernel(x_ref, q_ref, s_ref, d_ref, *, rows, chunk_bytes):
-    q, scale = quant_rows(x_ref[...])            # (rows, LANE_COLS)
-    q_ref[...] = q
-    s_ref[...] = scale
-    # lanes of the packed int8 stream this block contributes: row-major
-    # q bytes, 4 per lane, little-endian — identical to the host view of
-    # the packed payload's q region
-    b = (q.astype(jnp.int32) & 0xFF).astype(jnp.uint32) \
-        .reshape(rows, LANE_COLS // 4, 4)
-    lanes = (b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16)
-             | (b[..., 3] << 24)).reshape(1, rows * (LANE_COLS // 4))
-    pos = jax.lax.broadcasted_iota(jnp.uint32, lanes.shape, 1) \
-        + jnp.uint32(1)
-    acc = []
-    for s, ln in zip(_SEEDS, _LEN):
-        w = _fmix32_jnp(pos ^ jnp.uint32(s)) | jnp.uint32(1)
-        acc.append(jnp.sum(lanes * w, dtype=jnp.uint32)
-                   + jnp.uint32(chunk_bytes) * jnp.uint32(ln))
-    d_ref[0, :] = jnp.stack(acc)
+def _quant_fp_kernel(x_ref, q_ref, s_ref, d_ref):
+    rows = x_ref.shape[0]                          # one digest chunk
+    sr = slab_rows(rows)
+    lanes_per_row = LANE_COLS // LANE_BYTES
+    col = jax.lax.broadcasted_iota(jnp.uint32, (sr, LANE_COLS), 1)
+    # byte c of a row is byte c % 4 of lane c // 4 (little-endian), so
+    # lane * w == sum over its bytes of byte * (w << 8 * (c % 4)) mod 2^32
+    shift = (col & jnp.uint32(3)) * jnp.uint32(8)
+
+    def body(r0, accs):
+        q, s = quant_rows(x_ref[pl.ds(r0, sr), :])
+        q_ref[pl.ds(r0, sr), :] = q
+        s_ref[pl.ds(r0, sr), :] = s
+        byte = (q.astype(jnp.int32) & 0xFF).astype(jnp.uint32)
+        row = jax.lax.broadcasted_iota(jnp.uint32, (sr, LANE_COLS), 0) \
+            + r0.astype(jnp.uint32)
+        pos = row * jnp.uint32(lanes_per_row) + (col >> 2) + jnp.uint32(1)
+        return tuple(a + _fold_rows(_i32(byte * (_weights(pos, sd) << shift)))
+                     for a, sd in zip(accs, _SEEDS))
+
+    zero = jnp.zeros((8 if sr % 8 == 0 else sr, LANE_COLS), jnp.int32)
+    accs = for_slabs(rows, body, (zero,) * 4)
+    d_ref[...] = _put_columns([
+        jnp.sum(jnp.sum(a, axis=1, keepdims=True), axis=0, keepdims=True)
+        for a in accs])
 
 
 def quantize_fingerprint_blocks(x, chunk_bytes: int, *,
@@ -303,27 +360,28 @@ def quantize_fingerprint_blocks(x, chunk_bytes: int, *,
     ``(q int8 (R, LANE_COLS), scales f32 (R,), digests uint32 (nc, 4))``
     where digest j covers q-stream bytes [j*chunk_bytes, (j+1)*chunk_bytes)
     — the quantized payload never leaves VMEM unfingerprinted, so clean
-    chunks are known before any D2H copy."""
+    chunks are known before any D2H copy. The digest leaves the kernel as
+    an (nc, 1, 4) block per chunk, whose last two dims are whole."""
     R, C = x.shape
     assert C == LANE_COLS, (R, C)
     assert chunk_bytes % C == 0, (chunk_bytes, C)
     rows = chunk_bytes // C
     assert R % rows == 0, (R, rows)
     nc = R // rows
-    kernel = functools.partial(_quant_fp_kernel, rows=rows,
-                               chunk_bytes=chunk_bytes)
-    return pl.pallas_call(
-        kernel,
+    q, s, acc = pl.pallas_call(
+        _quant_fp_kernel,
         grid=(nc,),
         in_specs=[pl.BlockSpec((rows, C), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((rows, C), lambda i: (i, 0)),
-                   pl.BlockSpec((rows,), lambda i: (i,)),
-                   pl.BlockSpec((1, 4), lambda i: (i, 0))],
+                   pl.BlockSpec((rows, 1), lambda i: (i, 0)),
+                   pl.BlockSpec((None, 1, 4), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.int8),
-                   jax.ShapeDtypeStruct((R,), jnp.float32),
-                   jax.ShapeDtypeStruct((nc, 4), jnp.uint32)],
+                   jax.ShapeDtypeStruct((R, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((nc, 1, 4), jnp.int32)],
         interpret=interpret,
     )(x)
+    lens = jnp.full((nc, 1), chunk_bytes, jnp.uint32)
+    return q, s.reshape(R), _finish_digests(acc.reshape(nc, 4), lens)
 
 
 # ------------------------------------------- fused quant+digest (device path)
@@ -333,12 +391,22 @@ def _quant_fp_ref_jit(padded, chunk_bytes: int):
     (q int8 rows then f32 scales — the packed payload minus its header)
     in one compiled pass. Bit-identical to the Pallas kernels."""
     q, s = quant_rows(padded)
+    s = s.reshape(-1)
     rows = q.shape[0]
     qlanes = lanes_u32(q.reshape(-1))
     slanes = jax.lax.bitcast_convert_type(s, jnp.uint32)
     lanes = jnp.concatenate([qlanes, slanes])
     nbytes = rows * LANE_COLS + rows * 4
     return q, s, _digest_lane_stream(lanes, nbytes, chunk_bytes)
+
+
+def fused_kernel_fits(n_rows: int, chunk_bytes: int) -> bool:
+    """Whether the fused kernel takes at least one chunk of an (n_rows,
+    LANE_COLS) tensor on the chip: a chunk must be whole int8 row tiles
+    (``chunk_bytes`` a multiple of SLAB_ROWS rows) and the q region must
+    hold at least one whole chunk."""
+    return (chunk_bytes % (LANE_COLS * SLAB_ROWS) == 0
+            and n_rows * LANE_COLS >= chunk_bytes)
 
 
 def quant_fingerprint(padded, chunk_bytes: int):
@@ -349,19 +417,22 @@ def quant_fingerprint(padded, chunk_bytes: int):
     TPU: the fused Pallas kernel covers every chunk made purely of q
     bytes (quant + digest in one VMEM pass); the ragged tail (q remainder
     + the scales region) is digested from jit-assembled lanes. Other
-    backends run the whole thing as one jitted XLA program."""
-    if jax.default_backend() != "tpu" or chunk_bytes % LANE_COLS != 0:
-        q, s, d = _quant_fp_ref_jit(padded, chunk_bytes)
-        return q, s, np.asarray(d)
+    backends run the whole thing as one jitted XLA program. On TPU, a
+    shape the fused kernel cannot take (``fused_kernel_fits``) runs the
+    oracle too; the tracer counts each way under
+    ``quant_fingerprint.kernel`` / ``quant_fingerprint.oracle``."""
     R = padded.shape[0]
-    qbytes = R * LANE_COLS
-    body = qbytes // chunk_bytes
-    body_rows = body * (chunk_bytes // LANE_COLS)
-    if body_rows == 0:
+    if jax.default_backend() != "tpu":
         q, s, d = _quant_fp_ref_jit(padded, chunk_bytes)
         return q, s, np.asarray(d)
+    if not fused_kernel_fits(R, chunk_bytes):
+        trace.count("quant_fingerprint.oracle")
+        q, s, d = _quant_fp_ref_jit(padded, chunk_bytes)
+        return q, s, np.asarray(d)
+    trace.count("quant_fingerprint.kernel")
+    body = R * LANE_COLS // chunk_bytes
+    body_rows = body * (chunk_bytes // LANE_COLS)
     qb, sb, db = quantize_fingerprint_blocks(padded[:body_rows], chunk_bytes)
-    from .quantize import quantize_blocks
     if body_rows < R:
         qt, st = quantize_blocks(padded[body_rows:])
         q = jnp.concatenate([qb, qt])
@@ -374,9 +445,10 @@ def quant_fingerprint(padded, chunk_bytes: int):
 
 @functools.partial(jax.jit, static_argnames=("chunk_bytes", "body"))
 def _quant_tail_digests_jit(q, s, chunk_bytes: int, body: int):
-    rows = q.shape[0]
-    lanes = jnp.concatenate([lanes_u32(q.reshape(-1)),
+    """Digests of the qs-stream's chunks from chunk ``body`` on: the q
+    bytes past the fused kernel's whole chunks, then the scales."""
+    start = body * chunk_bytes
+    lanes = jnp.concatenate([lanes_u32(q.reshape(-1)[start:]),
                              jax.lax.bitcast_convert_type(s, jnp.uint32)])
-    nbytes = rows * LANE_COLS + rows * 4
-    cl = chunk_bytes // LANE_BYTES
-    return _digest_lane_stream(lanes, nbytes, chunk_bytes)[body:]
+    nbytes = q.size - start + s.size * 4
+    return _digest_lane_stream(lanes, nbytes, chunk_bytes)

@@ -5,6 +5,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .quantize import round_div
+
 
 def quantize_blocks_ref(x):
     """x: (R, C) -> (int8 (R, C), f32 scales (R,)); one group per row."""
@@ -12,7 +14,7 @@ def quantize_blocks_ref(x):
     absmax = jnp.max(jnp.abs(x), axis=1)
     # reciprocal multiply, matching the kernel (see _quant_kernel)
     scale = jnp.where(absmax > 0, absmax * jnp.float32(1.0 / 127.0), 1.0)
-    q = jnp.clip(jnp.round(x / scale[:, None]), -127, 127).astype(jnp.int8)
+    q = jnp.clip(round_div(x, scale[:, None]), -127, 127).astype(jnp.int8)
     return q, scale.astype(jnp.float32)
 
 

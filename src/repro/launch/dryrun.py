@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
-from repro.launch.mesh import axis_types_kw, make_production_mesh
+from repro.launch.mesh import auto_axes, make_production_mesh
 from repro.launch.specs import input_specs
 from repro.models.config import SHAPES_BY_NAME
 from repro.train.steps import make_prefill_step, make_serve_step, make_train_step
@@ -185,7 +185,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
         t0 = time.perf_counter()
         if mesh_split is not None:
             mesh = jax.make_mesh(mesh_split, ("data", "model"),
-                                 **axis_types_kw(2))
+                                 axis_types=auto_axes(2))
         else:
             mesh = make_production_mesh(multi_pod=multi_pod)
         spec_info = input_specs(cfg, shape, mesh)

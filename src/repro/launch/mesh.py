@@ -7,19 +7,19 @@ device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def axis_types_kw(n):
-    """``axis_types=(Auto,)*n`` kwargs where the jax version has AxisType
-    (≥ 0.6); empty on older jax, whose meshes are Auto by default."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {} if at is None else {"axis_types": (at.Auto,) * n}
+def auto_axes(n: int) -> tuple:
+    """``axis_types`` for an n-axis mesh whose shardings the compiler may
+    propagate (``jax.make_mesh`` defaults to Explicit axes)."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=auto_axes(len(axes)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -28,4 +28,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     if data * model > n:
         raise ValueError(f"need {data * model} devices, have {n}")
     return jax.make_mesh((data, model), ("data", "model"),
-                         **axis_types_kw(2))
+                         axis_types=auto_axes(2))

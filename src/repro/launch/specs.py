@@ -42,12 +42,7 @@ def train_specs(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
     part = Partitioner(cfg, mesh, fsdp=fsdp)
     state_shape = jax.eval_shape(
         lambda: init_train_state(jax.random.key(0), cfg))
-    shardings = {
-        "params": part.param_shardings(state_shape["params"]),
-        "opt": part.opt_shardings(state_shape["opt"]["mu"]),
-        "step": part.replicated(),
-    }
-    shardings["opt"]["count"] = part.replicated()
+    shardings = part.train_state_shardings(state_shape)
     state_specs = _with_shardings(state_shape, shardings)
 
     bdim = _batch_entry(part, shape.global_batch)

@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_config
 from repro.core import EngineConfig
 from repro.data import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.optim import AdamWConfig
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -28,8 +29,8 @@ def build_trainer(args) -> Trainer:
     tcfg = TrainerConfig(
         steps=args.steps, ckpt_every=args.ckpt_every, ckpt_dir=args.ckpt_dir,
         ckpt_engine=args.engine, async_ckpt=not args.sync_ckpt,
-        multilevel_remote=args.remote_dir, log_every=args.log_every,
-        seed=args.seed)
+        multilevel_remote=args.remote_dir, keep=args.keep,
+        log_every=args.log_every, seed=args.seed)
     data_cfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq_len,
                           global_batch=args.batch, seed=args.seed,
                           frontend_len=cfg.frontend_len,
@@ -46,7 +47,7 @@ def build_trainer(args) -> Trainer:
                    engine_config=eng_cfg)
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="xlstm-350m")
     ap.add_argument("--steps", type=int, default=200)
@@ -65,6 +66,8 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
     ap.add_argument("--remote-dir", default="")
+    ap.add_argument("--keep", type=int, default=3,
+                    help="newest committed steps to retain")
     ap.add_argument("--engine", default="aggregated",
                     choices=["aggregated", "datastates", "snapshot",
                              "torchsave"])
@@ -76,8 +79,12 @@ def main() -> None:
     ap.add_argument("--queue-depth", type=int, default=64)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--json-out", default="")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main() -> None:
+    args = parse_args()
+    use_compile_cache()
     trainer = build_trainer(args)
     try:
         out = trainer.run()

@@ -141,6 +141,12 @@ class Partitioner:
         return {"mu": moments, "nu": moments,
                 "count": NamedSharding(self.mesh, P())}
 
+    def train_state_shardings(self, state_shape):
+        """Shardings for a whole ``init_train_state`` pytree (shapes)."""
+        return {"params": self.param_shardings(state_shape["params"]),
+                "opt": self.opt_shardings(state_shape["params"]),
+                "step": self.replicated()}
+
     # ------------------------------------------------------------ activations
     def batch_spec(self) -> P:
         return P(self.dp,)

@@ -107,15 +107,10 @@ class Trainer:
     def init_state(self):
         key = jax.random.key(self.tcfg.seed)
         if self.mesh is not None:
-            part = Partitioner(self.cfg, self.mesh)
             state_shape = jax.eval_shape(
                 lambda: init_train_state(key, self.cfg))
-            shardings = {
-                "params": part.param_shardings(state_shape["params"]),
-                "opt": part.opt_shardings(state_shape["opt"]["mu"]),
-                "step": part.replicated(),
-            }
-            shardings["opt"]["count"] = part.replicated()
+            shardings = Partitioner(self.cfg, self.mesh) \
+                .train_state_shardings(state_shape)
             with self.mesh:
                 state = jax.jit(lambda: init_train_state(key, self.cfg),
                                 out_shardings=shardings)()
@@ -143,39 +138,44 @@ class Trainer:
         trace.export_perfetto(os.path.join(d, "trace.json"))
         trace.export_prometheus(os.path.join(d, "metrics.prom"))
 
+    def resume(self, state):
+        """Restore the latest checkpoint onto ``state``'s placement.
+
+        Returns ``(state, start_step, restore_attr)``: the restored train
+        state (or ``state`` itself when there is nothing to resume), the
+        step to continue from, and where the resume time went. The data
+        pipeline's position is restored with it."""
+        latest = self._latest() if self.ckpt is not None else None
+        if latest is None:
+            return state, 0, {}
+        t0 = time.perf_counter()
+        restored = self.ckpt.restore(
+            state_template=self._full_state(state), step=latest)
+        restore_wall = time.perf_counter() - t0
+        state = restored["train"]
+        self.pipeline.load_state_dict(restored["data"])
+        start_step = int(np.asarray(state["step"]))
+        # stall attribution: where the resume time went (streaming
+        # restores overlap stages, so they no longer sum to wall)
+        rm = self.ckpt.last_restore_metrics
+        restore_attr = {"restore_seconds": restore_wall}
+        if rm is not None:
+            restore_attr.update(
+                restore_mode=rm.mode,
+                restore_read_stall_s=rm.read_stall_seconds,
+                restore_decode_s=rm.decode_seconds,
+                restore_assemble_s=rm.assemble_seconds,
+                restore_h2d_s=rm.h2d_seconds,
+                restore_overlap_s=rm.overlap_seconds,
+                restore_peak_staged_bytes=rm.peak_staged_bytes)
+        return state, start_step, restore_attr
+
     def _run_traced(self) -> dict:
         state, shardings = self.init_state()
-        step_fn = make_train_step(self.cfg, self.opt_cfg)
-        if self.mesh is not None:
-            step_fn = jax.jit(step_fn, donate_argnums=(0,))
-        else:
-            step_fn = jax.jit(step_fn, donate_argnums=(0,))
+        step_fn = jax.jit(make_train_step(self.cfg, self.opt_cfg),
+                          donate_argnums=(0,))
 
-        start_step = 0
-        restore_attr: dict = {}
-        if self.ckpt is not None:
-            latest = self._latest()
-            if latest is not None:
-                t0 = time.perf_counter()
-                restored = self.ckpt.restore(
-                    state_template=self._full_state(state), step=latest)
-                restore_wall = time.perf_counter() - t0
-                state = restored["train"]
-                self.pipeline.load_state_dict(restored["data"])
-                start_step = int(np.asarray(state["step"]))
-                # stall attribution: where the resume time went (streaming
-                # restores overlap stages, so they no longer sum to wall)
-                rm = self.ckpt.last_restore_metrics
-                restore_attr = {"restore_seconds": restore_wall}
-                if rm is not None:
-                    restore_attr.update(
-                        restore_mode=rm.mode,
-                        restore_read_stall_s=rm.read_stall_seconds,
-                        restore_decode_s=rm.decode_seconds,
-                        restore_assemble_s=rm.assemble_seconds,
-                        restore_h2d_s=rm.h2d_seconds,
-                        restore_overlap_s=rm.overlap_seconds,
-                        restore_peak_staged_bytes=rm.peak_staged_bytes)
+        state, start_step, restore_attr = self.resume(state)
 
         ckpt_block_s = 0.0
         ckpt_reported_block_s = 0.0      # sum of SaveMetrics.blocking_seconds
