@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device,
+in a training cell (1 - union of op intervals / window), in percent."""
+
+
+def read(run):
+    if run.device_trace is None or not run.steps:
+        return None
+    return 100.0 * run.device_trace.idle_share
