@@ -9,10 +9,13 @@ in float32, the control (the reference with every matrix product's
 operands rounded to float8 e4m3, one precision below the configuration's
 bfloat16) and the fault "half of the batch left out" planted in the
 reference put in the program's place. It prints, per seed, each one's
-``loss_gap``, ``grad_gap`` and ``update_gap`` against the float32
-reference. The fault "a step that returns its state unchanged" reads 1 on
-both leaf gaps by their definition and needs no run. The benchmark's own
-runs never run this.
+gaps against the float32 reference and its verdict at the cell's own
+limits (the configuration's ``limits``, through the same ``Checks`` that
+decides a run's ``correct``). It exits 1 if the program comes out not
+correct, or the control or the fault comes out correct, on any seed. The
+fault "a step that returns its state unchanged" reads 1 on both median
+gaps by their definition and needs no run. The benchmark's own runs never
+run this.
 """
 
 from __future__ import annotations
@@ -57,6 +60,7 @@ def main(argv=None) -> int:
     run = loop.Run(cell=args.workload, config=config, traffic=traffic,
                    seed=seeds[0], seconds=0)
     cell = loop.Cell(run, ckpt_dir)
+    ok = True
     try:
         for seed in seeds:
             t0 = time.perf_counter()
@@ -80,11 +84,24 @@ def main(argv=None) -> int:
             out["control"] = _gaps(ctl, ref)
             out["half_batch"] = _gaps(half, ref)
             out["seconds"] = time.perf_counter() - t0
+            verdicts = judge(config["limits"], out)
+            out["correct"] = verdicts
+            ok = ok and verdicts == WANT
             print(json.dumps(out), flush=True)
     finally:
         cell.close()
         shutil.rmtree(ckpt_dir, ignore_errors=True)
-    return 0
+    print(json.dumps({"seeds": len(seeds), "as_expected": ok}), flush=True)
+    return 0 if ok else 1
+
+
+# what each reading has to come out as at the cell's limits
+WANT = {"program": True, "control": False, "half_batch": False}
+
+
+def judge(limits: dict, out: dict) -> dict:
+    """``correct`` of each reading of one seed at the cell's ``limits``."""
+    return {k: check.limit_checks(out[k], limits).correct for k in WANT}
 
 
 def _gaps(a, ref) -> dict:

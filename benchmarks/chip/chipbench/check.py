@@ -1,13 +1,14 @@
 """The comparison that decides ``correct``.
 
 Training (every cell): the program's first three steps against the plain
-reference's, from the same seeded weights and tokens.
+reference's, from the same seeded weights and tokens. The configuration's
+``limits`` name which of these numbers are compared.
   * ``loss_gap``: the largest of the three steps' |loss - reference loss|
-    over the reference loss;
-  * ``grad_gap``: the worst leaf's gap between the norms of the first
-    gradient as AdamW took it, program against reference;
-  * ``update_gap``: the worst leaf's gap between the norms of the params'
-    change after three steps.
+    over the reference loss (``loss1_gap``: the first step's alone);
+  * ``grad_gap`` / ``grad_median_gap``: the worst / median leaf's gap
+    between the norms of the first gradient as AdamW took it;
+  * ``update_gap`` / ``update_median_gap``: the worst / median leaf's gap
+    between the norms of the params' change after three steps.
 A leaf's gap is taken against the larger of its own reference norm and the
 median leaf's. Leaves whose first reference gradient is under a thousandth
 of the median leaf's move under AdamW by round-off alone; both gaps leave
@@ -74,6 +75,15 @@ def training_gaps(prog, ref) -> dict[str, float]:
 
 NUMBERS = ("loss_gap", "loss1_gap", "grad_gap", "grad_median_gap",
            "update_gap", "update_median_gap")
+
+
+def limit_checks(gaps: dict, limits: dict, checks=None):
+    """``checks`` (a new ``Checks`` when None) with each number that
+    ``limits`` names beside its limit."""
+    checks = Checks() if checks is None else checks
+    for name, limit in limits.items():
+        checks.add(name, gaps[name], limit)
+    return checks
 
 
 def _finite(x) -> bool:

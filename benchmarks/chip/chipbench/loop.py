@@ -237,8 +237,7 @@ def compare_with_reference(run: Run, cell: Cell, prog) -> None:
                                   cell.state_shape["params"],
                                   cell.host_batches(3))
     gaps = check.training_gaps(prog, ref)
-    for k, limit in run.config["limits"].items():
-        run.checks.add(k, gaps[k], limit)
+    check.limit_checks(gaps, run.config["limits"], run.checks)
     run.io["gaps"] = {k: gaps[k] for k in check.NUMBERS}
     paths = S.param_paths(cell.state_shape["params"])
     run.io["leaves_left_out"] = [paths[i] for i in gaps["left_out"]]
@@ -251,7 +250,9 @@ def compare_with_reference(run: Run, cell: Cell, prog) -> None:
 @loop("train_save")
 def train_save(run: Run, cell: Cell, t_start: float, traced) -> None:
     tr = cell.trainer
-    every = int(run.traffic["save_every_steps"])
+    every = int(run.traffic["save_every_steps"])   # the cell's to set
+    if every < 1:
+        raise ValueError(f"save_every_steps must be at least 1, not {every}")
     state, losses, grad_norms = cell.first_steps(
         tr, cell.init_fn(cell.key), 3)
     upd = cell.upd_fn(cell.key, state["params"])

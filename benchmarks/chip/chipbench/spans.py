@@ -66,6 +66,26 @@ def attribute(events, root) -> dict[str, float]:
     return out
 
 
+def level0_io(ev) -> bool:
+    """A write or a sync of the level-0 engine, on whatever thread."""
+    return ev.tier == "level0" and ev.name.startswith("io.")
+
+
+def covered(events, root, keep) -> float:
+    """Seconds of ``root``'s wall during which at least one span for which
+    ``keep(span)`` holds was open, on any thread: the union of those spans'
+    intervals, clipped to the root."""
+    iv = sorted((max(e.t0, root.t0), min(e.t1, root.t1)) for e in events
+                if e.kind == "span" and e.span_id != root.span_id and keep(e)
+                and e.t1 > root.t0 and e.t0 < root.t1)
+    total, end = 0.0, root.t0
+    for a, b in iv:
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total
+
+
 def roots(events, name: str, t0: float | None = None,
           t1: float | None = None) -> list:
     """Spans called ``name`` that start inside [t0, t1) (all when open)."""

@@ -196,6 +196,7 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: int,
         "resumes": len(run.resume_s), "window_s": run.window_s,
         "window_compiles": run.window_compiles,
         "stall_s": run.stall_s, "flops_per_step": run.flops_per_step,
+        "save_spans": _save_spans(run),
         "leaves_left_out": run.io.get("leaves_left_out"),
         "worst_leaves": run.io.get("worst_leaves"),
         "gaps": run.io.get("gaps"),
@@ -203,6 +204,20 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: int,
     result["checks"] = {k: [_num(v), _num(lim)]
                         for k, (v, lim) in run.checks.items.items()}
     return result
+
+
+def _save_spans(run) -> list[dict]:
+    """Per save of a traced window: the innermost-span sweep of the save's
+    own thread, and the seconds with level-0 I/O in flight on any thread."""
+    from chipbench import spans
+    out = []
+    for root in spans.roots(run.spans, "save", *run.span_window):
+        attr = spans.attribute(run.spans, root)
+        out.append({"wall_s": root.t1 - root.t0,
+                    "sweep_s": {k: v for k, v in attr.items() if v},
+                    "level0_io_s": spans.covered(run.spans, root,
+                                                 spans.level0_io)})
+    return out
 
 
 def _num(x):

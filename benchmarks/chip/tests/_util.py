@@ -55,14 +55,9 @@ def small_files(name: str, *, save_every: int = 2):
     entry, config, traffic = cell_files(name)
     config = copy.deepcopy(config)
     m, t = config["model"], config["train"]
-    if m.get("block_pattern"):
-        m.update(num_layers=2, d_model=64, num_heads=2, num_kv_heads=2,
-                 vocab_size=512)
-        t.update(batch=2, seq_len=512, reference_rows_per_block=1)
-    else:
-        m.update(num_layers=2, d_model=64, num_heads=4, num_kv_heads=4,
-                 d_ff=96, vocab_size=512)
-        t.update(batch=1, seq_len=1024, reference_rows_per_block=1)
+    m.update(num_layers=2, d_model=64, num_heads=4, num_kv_heads=4,
+             d_ff=96, vocab_size=512)
+    t.update(batch=1, seq_len=1024, reference_rows_per_block=1)
     m["dtype"] = "float32"
     config["limits"] = dict(SMALL_LIMITS)
     traffic = dict(traffic)

@@ -19,9 +19,10 @@ def _nudge(state):
     return jax.tree_util.tree_unflatten(tdef, leaves)
 
 
-@pytest.mark.parametrize("cell", ["xlstm-350m.save_paced",
-                                  "stablelm-3b-8l.save_paced"])
-def test_altered_save_is_caught(cell, monkeypatch):
+@pytest.mark.parametrize("cell,check", [
+    ("stablelm-3b-8l.save_paced", "restored_leaves_differ"),
+    ("stablelm-3b-8l.resume", "restored_states_differ")])
+def test_altered_save_is_caught(cell, check, monkeypatch):
     from repro.core.checkpoint import CheckpointManager
     real = CheckpointManager.save
 
@@ -31,7 +32,7 @@ def test_altered_save_is_caught(cell, monkeypatch):
     monkeypatch.setattr(CheckpointManager, "save", save)
     res = run_small(cell)
     assert res["correct"] is False
-    assert res["checks"]["restored_leaves_differ"][0] >= 1
+    assert res["checks"][check][0] >= 1
 
 
 def test_altered_restore_is_caught(monkeypatch):
@@ -42,6 +43,6 @@ def test_altered_restore_is_caught(monkeypatch):
         restored, start, attr = real(self, state)
         return _nudge(restored), start, attr
     monkeypatch.setattr(Trainer, "resume", resume)
-    res = run_small("xlstm-350m.resume")
+    res = run_small("stablelm-3b-8l.resume")
     assert res["correct"] is False
     assert res["checks"]["restored_states_differ"][0] >= 1

@@ -9,8 +9,7 @@ import pytest
 
 from _util import run_small
 
-CELLS = ["xlstm-350m.save_paced", "xlstm-350m.resume",
-         "stablelm-3b-8l.save_paced"]
+CELLS = ["stablelm-3b-8l.save_paced", "stablelm-3b-8l.resume"]
 
 
 def unchanged_step(cfg, opt):

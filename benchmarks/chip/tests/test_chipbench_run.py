@@ -5,8 +5,11 @@ import os
 import shutil
 import subprocess
 import sys
+import tempfile
 
-from _util import CHIP, ROOT, run_small
+import pytest
+
+from _util import CHIP, ROOT, load_run_module, run_small, small_files
 
 CONTRACT = ["correct", "attempted", "failed", "metrics", "device"]
 
@@ -52,3 +55,19 @@ def test_benchmark_files_alone_exit_nonzero(tmp_path):
     p = _run_py(tmp_path, {"PYTHONPATH": ""})
     assert p.returncode != 0
     assert "metrics" not in p.stdout
+
+
+@pytest.mark.parametrize("every,error", [(None, KeyError), (0, ValueError)])
+def test_save_cadence_is_the_cells_to_set(every, error):
+    """The traffic mix gives no save cadence: a cell without one, or with
+    none at all, is refused before its window."""
+    bench, (entry, config, traffic) = small_files(
+        "stablelm-3b-8l.save_paced")
+    traffic.pop("save_every_steps")
+    if every is not None:
+        traffic["save_every_steps"] = every
+    with tempfile.TemporaryDirectory() as work, pytest.raises(error):
+        load_run_module().run_cell(
+            bench, entry["name"], 1, 1.0, 0, require_tpu=False,
+            compile_cache=False, files=(entry, config, traffic),
+            work_dir=work, log=lambda *a, **k: None)

@@ -43,7 +43,7 @@ def test_names_units_and_keys():
             assert UNIT.match(m["unit"]) and m["better"] in ("lower",
                                                              "higher")
     assert {m["name"] for m in BENCH["end_to_end"]} == {
-        "train_tokens_per_s", "save_stall_s", "setup_s"}
+        "train_tokens_per_s", "save_stall_s", "resume_s", "setup_s"}
 
 
 def test_bounds():

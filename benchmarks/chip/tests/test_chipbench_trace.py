@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from _util import CHIP  # noqa: F401  (puts the benchmark on sys.path)
-from chipbench import devtrace, spans
+from chipbench import devtrace, spans, spec
 
 
 def ev(name, t0, t1):
@@ -90,3 +90,46 @@ def test_span_sweep_matches_stall_report(tmp_path):
     got = spans.attribute(events, roots[-1])
     assert got == pytest.approx(want.attribution)
     assert sum(got.values()) == pytest.approx(roots[-1].t1 - roots[-1].t0)
+
+
+def span(name, t0, t1, tid, tier="", sid=0):
+    return NS(kind="span", name=name, tier=tier, t0=t0, t1=t1, tid=tid,
+              span_id=sid)
+
+
+def test_covered_unions_threads_within_the_root():
+    root = span("save", 10.0, 20.0, tid=1, sid=1)
+    events = [root,
+              span("io.write", 8.0, 12.0, tid=2, tier="level0", sid=2),
+              span("io.write", 11.0, 13.0, tid=3, tier="level0", sid=3),
+              span("io.write", 15.0, 16.0, tid=2, tier="level0", sid=4),
+              span("io.fsync", 19.0, 25.0, tid=1, tier="level0", sid=5),
+              span("snapshot", 12.0, 18.0, tid=1, sid=6)]
+    io = [e for e in events if e.tier == "level0"]
+    assert spans.covered(events, root, lambda e: e in io) == \
+        pytest.approx(3.0 + 1.0 + 1.0)     # 10-13, 15-16, 19-20
+
+
+def test_level0_write_reads_the_pool_threads(tmp_path):
+    """Under the threadpool backend the writes run on the pool's threads;
+    the reader counts them, where the sweep of the save's own thread sees
+    only its waits."""
+    from repro.core import CheckpointManager, trace
+    from repro.core.engines import EngineConfig
+    state = {"w": np.arange(1 << 20, dtype=np.float32), "step": 3}
+    trace.enable()
+    try:
+        mgr = CheckpointManager(str(tmp_path / "ck"), async_save=True,
+                                config=EngineConfig(backend="threadpool"))
+        mgr.save(1, state)
+        mgr.wait()
+        mgr.close()
+        events = trace.drain()
+    finally:
+        trace.disable()
+    (root,) = spans.roots(events, "save")
+    writes = [e for e in events if e.kind == "span" and e.name == "io.write"]
+    assert writes and all(e.tid != root.tid for e in writes)
+    run = NS(spans=events, span_window=(root.t0, root.t1 + 1))
+    got = spec.metric_reader("level0_write_s").read(run)
+    assert max(e.t1 - e.t0 for e in writes) <= got <= root.t1 - root.t0
