@@ -63,6 +63,10 @@ from .serialization import (LEAN_KEY, TensorStub, as_bytes_view,
                             reinsert_tensors, serialize_lean, tensor_nbytes,
                             to_numpy_view)
 
+# annotated spans (the save/restore roots, snapshot.wait) also land on the
+# jax profiler's timeline as ckpt.<name>: trace.profiler_offset aligns them
+trace.set_annotation_factory(jax.profiler.TraceAnnotation)
+
 _STEP_RE = re.compile(r"^step_(\d{8})$")
 _ASIDE_RE = re.compile(r"^(step_\d{8})\.tmp-old-")
 
@@ -524,7 +528,8 @@ class CheckpointManager:
         def run():
             try:
                 with trace.span("save", nbytes=metrics.total_bytes,
-                                attrs={"step": step, "mode": metrics.mode}):
+                                attrs={"step": step, "mode": metrics.mode},
+                                annotate=True):
                     self._run_streaming_flush(step, puts, rank, num_ranks,
                                               rank_totals, metrics, t_start,
                                               quantized_keys, tmp, pipeline,
@@ -621,7 +626,8 @@ class CheckpointManager:
 
         def flush():
             with trace.span("save", nbytes=metrics.total_bytes,
-                            attrs={"step": step, "mode": metrics.mode}):
+                            attrs={"step": step, "mode": metrics.mode},
+                            annotate=True):
                 t1 = trace.clock()
                 with trace.span("flush", tier="level0",
                                 nbytes=metrics.total_bytes):
@@ -705,8 +711,11 @@ class CheckpointManager:
         (JAX rebinding needs no barrier: old arrays stay alive and
         immutable while the pipeline references them.)"""
         ev = self._snapshot_staged
-        if ev is not None:
-            ev.wait()
+        if ev is not None and not ev.is_set():
+            sm = self.last_save_metrics
+            with trace.span("snapshot.wait", annotate=True,
+                            nbytes=sm.total_bytes if sm else 0):
+                ev.wait()
 
     def wait(self) -> None:
         """Block until any in-flight async flush committed."""
@@ -772,7 +781,7 @@ class CheckpointManager:
 
     def _restore_from(self, ckpt: str, step: int, state_template, shardings,
                       prefetch, t_start: float, window_fn=None):
-        with trace.span("restore", attrs={"step": step}):
+        with trace.span("restore", attrs={"step": step}, annotate=True):
             return self._restore_from_traced(ckpt, step, state_template,
                                              shardings, prefetch, t_start,
                                              window_fn)

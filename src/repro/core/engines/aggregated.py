@@ -161,7 +161,9 @@ class _AggSaveStream(SaveStream):
         e = self.extents[key]
         g = self._group_of[key]
         if cfg.checksum:
-            self.crcs[key] = zlib.crc32(mv, self.crcs.get(key, 0)) & 0xFFFFFFFF
+            with trace.span("crc", nbytes=mv.nbytes):
+                self.crcs[key] = (zlib.crc32(mv, self.crcs.get(key, 0))
+                                  & 0xFFFFFFFF)
         if g.large:
             expect = self._pos.get(key, 0)
             if pos != expect:
@@ -180,6 +182,7 @@ class _AggSaveStream(SaveStream):
                 tb = trace.clock()
                 buf.view(0, n)[:] = mv[p:p + n]
                 tc = trace.clock()
+                trace.complete("stage.copy", tb, tc, nbytes=n)
                 self.stats.alloc_seconds += tb - ta
                 self.stats.copy_seconds += tc - tb
                 self._submit(self.fds[e.path], e.offset + pos + p, buf,
@@ -203,7 +206,9 @@ class _AggSaveStream(SaveStream):
         if mv.nbytes:
             tb = trace.clock()
             g.buf.view(e.offset - first.offset, e.nbytes)[:] = mv
-            self.stats.copy_seconds += trace.clock() - tb
+            tc = trace.clock()
+            trace.complete("stage.copy", tb, tc, nbytes=e.nbytes)
+            self.stats.copy_seconds += tc - tb
         g.filled += e.nbytes
         g.seen += 1
         if g.seen == len(g.extents) and not g.submitted:
@@ -403,7 +408,12 @@ class _AggReadStream(ReadStream):
     def _pump(self, wait_for: str | None = None, drain: bool = False) -> None:
         self._submit_admitted(wait_for, drain)
         if self.io.inflight:
+            # this thread blocked on the disk and nothing else
+            t0 = trace.clock()
             cs = self.io.poll(min_n=1)
+            if trace.is_enabled():
+                trace.complete("read.wait", t0, nbytes=sum(
+                    self._handlers[c.user_data][1].span for c in cs))
         else:
             cs = self.io.poll()   # drain engines that complete inline (posix)
         for c in cs:
@@ -425,7 +435,9 @@ class _AggReadStream(ReadStream):
             self.budget.sub(buf.nbytes)
             buf.release()
             self.budget.add(landed)
-            self.stats.copy_seconds += trace.clock() - tb
+            tc = trace.clock()
+            trace.complete("read.land", tb, tc, nbytes=landed)
+            self.stats.copy_seconds += tc - tb
             for e in unit.group:     # verify AFTER the books are settled
                 self._verify_whole(e)
         else:
@@ -440,7 +452,9 @@ class _AggReadStream(ReadStream):
             self._left[unit.key] -= unit.n
             if self._left[unit.key] == 0:
                 self._done[unit.key] = self._dest.pop(unit.key)
-            self.stats.copy_seconds += trace.clock() - tb
+            tc = trace.clock()
+            trace.complete("read.land", tb, tc, nbytes=unit.n)
+            self.stats.copy_seconds += tc - tb
             self._advance_crc(e, dest, unit.pos, unit.n)
 
     # ------------------------------------------------------ CRC verification
@@ -448,7 +462,8 @@ class _AggReadStream(ReadStream):
         expect = self.crcs.get(e.key)
         if expect is None:
             return
-        got = zlib.crc32(self._done[e.key]) & 0xFFFFFFFF
+        with trace.span("crc", nbytes=e.nbytes):
+            got = zlib.crc32(self._done[e.key]) & 0xFFFFFFFF
         if got != expect:
             raise ChecksumError(e.key, e.path, e.offset, expect, got)
 
@@ -463,7 +478,8 @@ class _AggReadStream(ReadStream):
         st[2][pos] = n
         while st[1] in st[2]:
             m = st[2].pop(st[1])
-            st[0] = zlib.crc32(dest[st[1]:st[1] + m], st[0]) & 0xFFFFFFFF
+            with trace.span("crc", nbytes=m):
+                st[0] = zlib.crc32(dest[st[1]:st[1] + m], st[0]) & 0xFFFFFFFF
             st[1] += m
         if st[1] == e.nbytes and st[0] != expect:
             raise ChecksumError(e.key, e.path, e.offset, expect, st[0])

@@ -11,7 +11,7 @@ is the shared instrument:
     pipeline worker, the io_uring reaper, the level-1 flush thread, and the
     rget pool land on one comparable timeline,
   · spans carry ``(name, tier, bytes, attrs, parent)``; events are instant
-    marks (hedge issue/win, injected faults); counters/histograms aggregate,
+    marks (hedge issue/win, injected faults); counters aggregate,
   · per-thread ring buffers — appends touch only thread-local state (no
     lock on the hot path); overflow drops the OLDEST events and counts the
     drops, so a long soak degrades to "recent history" instead of OOM,
@@ -21,10 +21,14 @@ is the shared instrument:
     compiled into hot loops permanently,
   · two exporters: Chrome/Perfetto ``trace.json`` (spans as ``X`` events on
     tier-named tracks — open in ui.perfetto.dev, pipeline overlap is
-    visually inspectable) and a Prometheus-style textfile of
-    counters/histograms,
-  · ``MetricsRegistry``: adapts the stack's existing Stats dataclasses
-    (live, by reference — no copy at registration) into one queryable tree,
+    visually inspectable) and a Prometheus-style textfile of counters and
+    per-span duration histograms,
+  · one clock with the device trace: spans opened with ``annotate=True``
+    (the save/restore roots and ``snapshot.wait``) also open a profiler
+    annotation ``ckpt.<name>`` through a factory the jax-importing layer
+    installs (``set_annotation_factory``); ``profiler_offset`` pairs those
+    annotations with their spans and returns the offset that maps
+    ``clock()`` onto the profiler's nanoseconds,
   · ``stall_report()``: attributes a save/restore span's wall time to
     {compute, d2h, stage_wait, level0_write, level1_flush, remote_put,
     remote_get, barrier} by same-thread span self-times, so the attribution
@@ -37,12 +41,13 @@ the one timing primitive in ``core/**`` (CRL006).
 
 from __future__ import annotations
 
+import bisect
 import itertools
 import json
 import re
 import threading
 import time
-from dataclasses import dataclass, fields as _dc_fields, is_dataclass
+from dataclasses import dataclass
 
 # --------------------------------------------------------------------- clock
 # The process trace epoch: set once at import, shared by every thread. All
@@ -105,9 +110,9 @@ class _Ring:
 
 
 class Tracer:
-    """Recording state: per-thread rings + aggregated counters/histograms."""
+    """Recording state: per-thread rings + aggregated counters."""
 
-    # exponential latency buckets (seconds) for histograms
+    # exponential latency buckets (seconds) for the span histograms
     BUCKETS = (1e-5, 1e-4, 1e-3, 1e-2, 0.1, 1.0, 10.0)
 
     def __init__(self, capacity: int = 1 << 16):
@@ -121,8 +126,6 @@ class Tracer:
         self._ids = itertools.count(1)
         # crlint: guarded-by(_lock)
         self._counters: dict[str, float] = {}
-        # crlint: guarded-by(_lock)
-        self._hists: dict[str, list] = {}   # name -> [bucket_counts, sum, n]
 
     def _ring(self) -> _Ring:
         r = getattr(self._local, "ring", None)
@@ -137,21 +140,6 @@ class Tracer:
     def count(self, name: str, value: float = 1.0) -> None:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0.0) + value
-
-    def observe(self, name: str, value: float) -> None:
-        with self._lock:
-            h = self._hists.get(name)
-            if h is None:
-                h = self._hists[name] = [[0] * (len(self.BUCKETS) + 1),
-                                         0.0, 0]
-            for i, edge in enumerate(self.BUCKETS):
-                if value <= edge:
-                    h[0][i] += 1
-                    break
-            else:
-                h[0][-1] += 1
-            h[1] += value
-            h[2] += 1
 
     def events(self) -> list[TraceEvent]:
         """Snapshot of every thread's ring, globally time-ordered."""
@@ -212,20 +200,36 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+# Factory of profiler annotations for spans opened with ``annotate=True``.
+# The layer that imports jax installs one (core/checkpoint.py installs
+# ``jax.profiler.TraceAnnotation``), so this module stays stdlib-only.
+_ANNOTATION_FACTORY = None
+ANNOTATION_PREFIX = "ckpt."
+
+
+def set_annotation_factory(factory) -> None:
+    """Install ``factory(name)``, which returns a context manager that
+    marks ``name`` on the profiler's timeline; ``None`` removes it."""
+    global _ANNOTATION_FACTORY
+    _ANNOTATION_FACTORY = factory
+
+
 class _Span:
     """Context-manager span; records on exit into the exiting thread's ring."""
 
     __slots__ = ("tr", "name", "tier", "nbytes", "parent", "attrs",
-                 "t0", "id", "_ring")
+                 "t0", "id", "_ring", "annotate", "_ann")
 
     def __init__(self, tr: Tracer, name: str, tier: str, nbytes: int,
-                 parent: int | None, attrs: dict | None):
+                 parent: int | None, attrs: dict | None, annotate: bool):
         self.tr = tr
         self.name, self.tier, self.nbytes = name, tier, nbytes
         self.parent, self.attrs = parent, attrs
         self.t0 = 0.0
         self.id = 0
         self._ring: _Ring | None = None
+        self.annotate = annotate
+        self._ann = None
 
     def __enter__(self) -> "_Span":
         ring = self._ring = self.tr._ring()
@@ -233,11 +237,20 @@ class _Span:
         if self.parent is None:
             self.parent = ring.stack[-1] if ring.stack else 0
         ring.stack.append(self.id)
+        factory = _ANNOTATION_FACTORY
+        if self.annotate and factory is not None:
+            # made right before t0 (a TraceMe stamps its start when it is
+            # constructed) and closed right after t1, on this thread: the
+            # pair that profiler_offset() aligns
+            self._ann = factory(ANNOTATION_PREFIX + self.name)
+            self._ann.__enter__()
         self.t0 = clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = clock()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         ring = self._ring
         if ring.stack and ring.stack[-1] == self.id:
             ring.stack.pop()
@@ -250,14 +263,17 @@ class _Span:
 
 
 def span(name: str, tier: str = "host", nbytes: int = 0,
-         parent: int | None = None, attrs: dict | None = None):
+         parent: int | None = None, attrs: dict | None = None,
+         annotate: bool = False):
     """Open a span; ``with trace.span("flush", tier="level0", nbytes=n):``.
 
+    ``annotate=True`` also opens a profiler annotation ``ckpt.<name>`` for
+    the span's extent, when a factory is installed.
     Disabled mode returns the shared no-op singleton (no allocation)."""
     tr = _TRACER
     if tr is None:
         return _NOOP
-    return _Span(tr, name, tier, nbytes, parent, attrs)
+    return _Span(tr, name, tier, nbytes, parent, attrs, annotate)
 
 
 def complete(name: str, t0: float, t1: float | None = None, *,
@@ -299,11 +315,46 @@ def count(name: str, value: float = 1.0) -> None:
     tr.count(name, value)
 
 
-def observe(name: str, value: float) -> None:
-    tr = _TRACER
-    if tr is None:
-        return
-    tr.observe(name, value)
+def profiler_offset(events: list[TraceEvent],
+                    annotations) -> tuple[float, float]:
+    """The offset that maps ``clock()`` onto a profiler trace's clock.
+
+    ``annotations`` are ``(name, start_ns)`` pairs read from the profiler's
+    trace; those named ``ckpt.<name>`` are paired with the ``<name>`` span
+    each opened with. The offset is the one on which most annotations find
+    a span of their name within a millisecond, refined to the median of
+    those pairs. Returns ``(offset_ns, worst_ns)``: a span's start on the
+    profiler's clock is ``t0 * 1e9 + offset_ns``, and ``worst_ns`` is the
+    largest disagreement of a pair. Raises ``ValueError`` when no
+    annotation has a span of its name."""
+    starts: dict[str, list[float]] = {}
+    for e in events:
+        if e.kind == "span":
+            starts.setdefault(e.name, []).append(e.t0 * 1e9)
+    for v in starts.values():
+        v.sort()
+    n = len(ANNOTATION_PREFIX)
+    anns = [(a[n:], float(t)) for a, t in annotations
+            if a.startswith(ANNOTATION_PREFIX) and a[n:] in starts]
+    if not anns:
+        raise ValueError("no ckpt.* annotation has a span of its name")
+
+    def residuals(off: float) -> list[float]:
+        """Per annotation, its distance to the nearest span of its name
+        under ``off``; those within a millisecond are the pairs."""
+        out = []
+        for name, t in anns:
+            s = starts[name]
+            i = bisect.bisect_left(s, t - off)
+            out.append(min((t - off - s[j] for j in (i - 1, i)
+                            if 0 <= j < len(s)), key=abs))
+        return [d for d in out if abs(d) <= 1e6]
+
+    best = max((t - s for name, t in anns for s in starts[name]),
+               key=lambda off: len(residuals(off)))
+    ds = sorted(residuals(best))
+    off = best + ds[len(ds) // 2]
+    return off, max(abs(d) for d in residuals(off))
 
 
 def drain() -> list[TraceEvent]:
@@ -366,7 +417,7 @@ def _prom_name(name: str) -> str:
 def export_prometheus(path: str | None = None,
                       events: list[TraceEvent] | None = None) -> str:
     """Prometheus textfile exposition: explicit counters, the dropped-event
-    counter, and per-span-name duration/byte histograms derived from the
+    counter, and per-span-name duration histograms derived from the
     recorded spans."""
     tr = _TRACER
     evs = drain() if events is None else events
@@ -394,10 +445,6 @@ def export_prometheus(path: str | None = None,
             h[0][-1] += 1
         h[1] += d
         h[2] += 1
-    explicit = tr._hists if tr is not None else {}
-    with (tr._lock if tr is not None else threading.Lock()):
-        for name, h in sorted(explicit.items()):
-            hists[(name, "")] = [list(h[0]), h[1], h[2]]
     for (name, tier), (buckets, total, n) in sorted(hists.items()):
         m = f"crtrace_span_seconds_{_prom_name(name)}"
         tag = f'{{tier="{tier}"}}' if tier else ""
@@ -422,74 +469,6 @@ def export_prometheus(path: str | None = None,
         with open(path, "w", encoding="utf-8") as f:
             f.write(text)
     return text
-
-
-# ----------------------------------------------------------- metrics registry
-class MetricsRegistry:
-    """One queryable tree over the stack's live Stats objects.
-
-    ``register`` takes an object OR a zero-arg callable resolved at
-    ``snapshot()`` time; nothing is copied at registration, so a snapshot
-    always reflects the source's CURRENT field values (including computed
-    ``@property`` views like ``flush_gbps``). Dataclasses adapt recursively;
-    dicts/lists adapt element-wise; everything else passes through."""
-
-    def __init__(self):
-        self._sources: dict[str, object] = {}
-
-    def register(self, name: str, source) -> None:
-        self._sources[name] = source
-
-    def unregister(self, name: str) -> None:
-        self._sources.pop(name, None)
-
-    def names(self) -> list[str]:
-        return sorted(self._sources)
-
-    @staticmethod
-    def _adapt(obj, depth: int = 0):
-        if depth > 6 or obj is None or isinstance(obj, (bool, int, float,
-                                                        str)):
-            return obj
-        if is_dataclass(obj) and not isinstance(obj, type):
-            out = {f.name: MetricsRegistry._adapt(getattr(obj, f.name),
-                                                  depth + 1)
-                   for f in _dc_fields(obj)}
-            for k in dir(type(obj)):
-                if isinstance(getattr(type(obj), k, None), property):
-                    try:
-                        out[k] = MetricsRegistry._adapt(getattr(obj, k),
-                                                        depth + 1)
-                    except Exception as e:
-                        out[k] = f"<error: {e!r}>"
-            return out
-        if isinstance(obj, dict):
-            return {str(k): MetricsRegistry._adapt(v, depth + 1)
-                    for k, v in obj.items()}
-        if isinstance(obj, (list, tuple)):
-            return [MetricsRegistry._adapt(v, depth + 1) for v in obj]
-        if hasattr(obj, "as_dict"):
-            return MetricsRegistry._adapt(obj.as_dict(), depth + 1)
-        try:                       # numpy scalars and friends
-            return float(obj)
-        except (TypeError, ValueError):
-            return repr(obj)
-
-    def snapshot(self) -> dict:
-        out = {}
-        for name, src in self._sources.items():
-            obj = src() if callable(src) else src
-            out[name] = self._adapt(obj)
-        return out
-
-    def query(self, path: str):
-        """Dotted lookup into a fresh snapshot: ``query("save.flush_gbps")``."""
-        node = self.snapshot()
-        for part in path.split("."):
-            if not isinstance(node, dict) or part not in node:
-                raise KeyError(path)
-            node = node[part]
-        return node
 
 
 # --------------------------------------------------------------- stall report

@@ -94,14 +94,6 @@ class Trainer:
         else:
             self.ckpt = None
         self.metrics_log: list[dict] = []
-        # one queryable tree over every Stats producer in the stack
-        self.registry = trace.MetricsRegistry()
-        if self.ckpt is not None:
-            self.registry.register(
-                "save", lambda: getattr(self.ckpt, "last_save_metrics", None))
-            self.registry.register(
-                "restore",
-                lambda: getattr(self.ckpt, "last_restore_metrics", None))
 
     # ------------------------------------------------------------------ state
     def init_state(self):
