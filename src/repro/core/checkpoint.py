@@ -260,6 +260,7 @@ class RestoreMetrics:
     prefetch_seconds: float = 0.0   # tier-1 → tier-0 extent staging
     end_to_end_seconds: float = 0.0
     peak_staged_bytes: int = 0      # max host bytes staged by the read stream
+    direct_bytes: int = 0           # read with no bounce copy (lone extents)
     mode: str = "monolithic"        # monolithic | streaming
 
     @property

@@ -21,6 +21,8 @@ degenerate client that gets every request and drains):
   · coalesced reads — one I/O per group region covering many small objects,
   · preallocated POOLED buffers (the fix for DataStates' dominant
     allocation cost), O_DIRECT reads for large transfers,
+  · an extent that stands alone is read straight into page-aligned memory
+    of its own, which ``get`` hands over: no bounce buffer, no landing copy,
   · per-request results surface the moment their extents land, so the
     consumer dequantizes/assembles/uploads tensor k while the reads for
     tensor k+1 are still in flight,
@@ -39,7 +41,7 @@ import numpy as np
 
 from .. import trace
 from ..aggregation import Extent, coalesce
-from ..buffers import BufferPool, StageBudget, align_up
+from ..buffers import AlignedBuffer, BufferPool, StageBudget, align_up
 from ..io_engine import IORequest, OP_READ, OP_WRITE
 from ..manifest import Manifest
 from .base import (ChecksumError, CREngine, IOStats, ReadReq, ReadStream,
@@ -274,7 +276,7 @@ class _AggSaveStream(SaveStream):
 
 class _ReadUnit:
     """One submission-granular read: a coalesced group region, or one chunk
-    of an extent larger than the (budget-clamped) chunk size."""
+    (at most the budget-clamped chunk size) of an extent that stands alone."""
 
     __slots__ = ("path", "file_off", "span", "group", "key", "pos", "n")
 
@@ -283,7 +285,15 @@ class _ReadUnit:
                  pos: int = 0, n: int = 0):
         self.path, self.file_off, self.span = path, file_off, span
         self.group = group          # members of a coalesced group, else None
-        self.key, self.pos, self.n = key, pos, n   # chunk of a large extent
+        self.key, self.pos, self.n = key, pos, n   # chunk of a lone extent
+
+    @property
+    def cost(self) -> int:
+        """Staged bytes this unit holds while in flight: a group's pooled
+        buffer size class, or a lone chunk's span."""
+        if self.group is None:
+            return self.span
+        return BufferPool.size_class(max(self.span, 1))
 
 
 class _AggReadStream(ReadStream):
@@ -295,9 +305,16 @@ class _AggReadStream(ReadStream):
     decode/assemble/H2D overlaps the reads still in flight. The budget counts
     read buffers in flight AND landed-but-unconsumed coalesced-group results,
     so a slow consumer throttles submission instead of ballooning host
-    memory. (A chunked large extent's destination array is consumer-owned
-    output — the result the ``get`` will hand over — and is not charged, the
-    same way the save stream never charges its caller's source arrays.)
+    memory.
+
+    An extent that coalesces with no neighbour is read, chunk by chunk,
+    straight into a fresh page-aligned buffer of its padded size, and
+    ``get`` returns a view of it: no pooled bounce buffer, no copy. Its
+    chunks count their span against the budget while in flight; once
+    landed, the buffer is consumer-owned output (the result ``get`` hands
+    over) and is not charged, the same way the save stream never charges
+    its caller's source arrays. It is never released to the pool: its
+    lifetime is that of the arrays that view it.
     """
 
     def __init__(self, eng: "AggregatedEngine", ckpt_dir: str,
@@ -324,17 +341,23 @@ class _AggReadStream(ReadStream):
             thr = min(thr, unit)
         self._units: deque[_ReadUnit] = deque()
         self._unsubmitted: dict[str, int] = {}   # key -> units still queued
-        self._dest: dict[str, np.ndarray] = {}   # chunked keys being filled
-        self._left: dict[str, int] = {}          # chunked: bytes not landed
+        self._landing: dict[str, AlignedBuffer] = {}  # lone: own memory
+        self._left: dict[str, int] = {}          # lone: bytes not landed
         self._crc_state: dict[str, list] = {}    # key -> [crc, pos, {pos: n}]
         self._done: dict[str, np.ndarray] = {}   # landed, awaiting get()
         self._staged_done: dict[str, int] = {}   # done bytes held in budget
         self._consumed: set[str] = set()
-        self._handlers: dict[int, tuple] = {}    # token -> (buf, unit)
+        # token -> (pooled buffer, or None for a lone chunk; unit)
+        self._handlers: dict[int, tuple] = {}
         self._token = 0
         for group in coalesce(list(self.extents.values()), thr, cfg.align):
             first, last = group[0], group[-1]
-            if len(group) == 1 and first.nbytes > self._chunk:
+            if len(group) == 1 and first.nbytes:
+                # mapped now, before any read is in flight: an mmap beside
+                # reads that fault in fresh pages waits on the process's
+                # memory-map lock. The reads fault the pages in.
+                self._landing[first.key] = AlignedBuffer(
+                    align_up(first.nbytes, cfg.align))
                 pos, n_units = 0, 0
                 while pos < first.nbytes:
                     n = min(self._chunk, first.nbytes - pos)
@@ -379,8 +402,7 @@ class _AggReadStream(ReadStream):
         hatch)."""
         while self._units and self.io.inflight < self.cfg.queue_depth:
             unit = self._units[0]
-            if not self.budget.admits(
-                    BufferPool.size_class(max(unit.span, 1))):
+            if not self.budget.admits(unit.cost):
                 if self.io.inflight or not (
                         drain or (wait_for is not None
                                   and wait_for not in self._done
@@ -390,14 +412,19 @@ class _AggReadStream(ReadStream):
             self._submit(unit)
 
     def _submit(self, unit: _ReadUnit) -> None:
-        ta = trace.clock()
-        buf = self.eng.pool.get(unit.span)
-        self.stats.alloc_seconds += trace.clock() - ta
-        self.budget.add(buf.nbytes)
+        if unit.group is None:
+            buf, target, at = None, self._landing[unit.key], unit.pos
+        else:
+            ta = trace.clock()
+            buf = target = self.eng.pool.get(unit.span)
+            self.stats.alloc_seconds += trace.clock() - ta
+            at = 0
+        self.budget.add(unit.cost)
         self._token += 1
         self._handlers[self._token] = (buf, unit)
         self.io.submit([IORequest(OP_READ, self.fds[unit.path], unit.file_off,
-                                  buf, 0, unit.span, user_data=self._token)])
+                                  target, at, unit.span,
+                                  user_data=self._token)])
         self.stats.io_requests += 1
         if unit.group is not None:
             for e in unit.group:
@@ -421,8 +448,8 @@ class _AggReadStream(ReadStream):
 
     def _complete(self, c) -> None:
         buf, unit = self._handlers.pop(c.user_data)
-        tb = trace.clock()
         if unit.group is not None:
+            tb = trace.clock()
             first = unit.group[0]
             landed = 0
             for e in unit.group:
@@ -442,19 +469,16 @@ class _AggReadStream(ReadStream):
                 self._verify_whole(e)
         else:
             e = self.extents[unit.key]
-            dest = self._dest.get(unit.key)
-            if dest is None:
-                dest = self._dest[unit.key] = np.empty(e.nbytes, np.uint8)
-            dest[unit.pos:unit.pos + unit.n] = np.frombuffer(
-                buf.view(0, unit.n), np.uint8)
-            self.budget.sub(buf.nbytes)
-            buf.release()
+            # landed in place: nothing to copy
+            self.budget.sub(unit.cost)
+            self.stats.direct_bytes += unit.n
+            trace.count("read.direct_bytes", unit.n)
+            dest = np.frombuffer(self._landing[unit.key].view(0, e.nbytes),
+                                 np.uint8)
             self._left[unit.key] -= unit.n
             if self._left[unit.key] == 0:
-                self._done[unit.key] = self._dest.pop(unit.key)
-            tc = trace.clock()
-            trace.complete("read.land", tb, tc, nbytes=unit.n)
-            self.stats.copy_seconds += tc - tb
+                self._done[unit.key] = dest
+                del self._landing[unit.key]
             self._advance_crc(e, dest, unit.pos, unit.n)
 
     # ------------------------------------------------------ CRC verification
@@ -545,10 +569,11 @@ class _AggReadStream(ReadStream):
         finally:
             self.eng._close_files(self.fds)
             for buf, _u in self._handlers.values():
-                buf.release()
+                if buf is not None:
+                    buf.release()
             self._handlers.clear()
             self._done.clear()
-            self._dest.clear()
+            self._landing.clear()
             self.budget.settle()
 
 

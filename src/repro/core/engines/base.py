@@ -109,6 +109,7 @@ class IOStats:
     copy_seconds: float = 0.0    # staging memcpy time
     io_seconds: float = 0.0      # submit+wait time
     peak_staged_bytes: int = 0   # max staged bytes in flight (backpressure)
+    direct_bytes: int = 0        # read straight into the array get() returns
 
     @property
     def gbps(self) -> float:

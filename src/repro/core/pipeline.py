@@ -220,14 +220,18 @@ class RestorePipeline:
         prefetcher pulls exactly these from the remote tier); ``crcs`` maps
         request keys to expected crc32s for in-stream verification.
         ``metrics`` (RestoreMetrics-shaped) gains stall/decode/assemble/h2d
-        seconds and the engine's peak staged bytes."""
+        seconds, the engine's peak staged bytes and the bytes it read with
+        no bounce copy. Where a saved shard fills its window one to one, the
+        assembler adopts the stream's array as the window, so those bytes
+        reach ``place`` without a host copy."""
         from . import quant_codec
         if place is None:
             place = lambda task, windows: next(iter(windows.values()))
         if metrics is None:
             metrics = SimpleNamespace(
                 read_seconds=0.0, read_stall_seconds=0.0, decode_seconds=0.0,
-                assemble_seconds=0.0, h2d_seconds=0.0, peak_staged_bytes=0)
+                assemble_seconds=0.0, h2d_seconds=0.0, peak_staged_bytes=0,
+                direct_bytes=0)
 
         # Plan: per task, one assembler per distinct window and the ordered
         # set of extents feeding them (a resharded restore reads a subset of
@@ -322,6 +326,7 @@ class RestorePipeline:
             stats = stream.end_restore()
             metrics.read_seconds = stats.seconds
             metrics.peak_staged_bytes = stats.peak_staged_bytes
+            metrics.direct_bytes = stats.direct_bytes
             return out
         except BaseException:
             # abort releases pooled buffers and settles the staged-byte

@@ -109,17 +109,26 @@ class WindowAssembler:
     bytes the moment its extent lands, so window assembly overlaps the reads
     still in flight. Coverage is validated up front by ``plan_window``;
     ``done`` flips once every contributing extent has been fed.
+
+    Where the last shard fed is the whole window (its index is the window),
+    its writable bytes become the window with no copy.
     """
 
     def __init__(self, record: TensorRecord, wanted: Index):
         self.record = record
         self.wanted = wanted
         self.dtype = record_dtype(record)
-        self.out = np.empty(window_shape(wanted), dtype=self.dtype)
+        pieces = plan_window(record, wanted)
         self._by_extent: dict[tuple[str, int], list[ReadPiece]] = {}
-        for piece in plan_window(record, wanted):
+        for piece in pieces:
             self._by_extent.setdefault(
                 (piece.shard.path, piece.shard.offset), []).append(piece)
+        # a window one shard fills exactly is expected to adopt it; any
+        # other is allocated now, before its reads are in flight (an mmap
+        # or munmap beside reads that fault in fresh pages waits on the
+        # process's memory-map lock)
+        self.out = None if len(pieces) == 1 and pieces[0].exact else \
+            np.empty(window_shape(wanted), dtype=self.dtype)
 
     def pending_shards(self) -> list[ShardEntry]:
         """One ShardEntry per extent still needed (dedup: an extent feeding
@@ -135,6 +144,15 @@ class WindowAssembler:
         sh_shape = window_shape(tuple(shard.index))
         n = int(np.prod(sh_shape, dtype=np.int64))
         arr = np.asarray(raw).view(self.dtype)[:n].reshape(sh_shape)
+        if (len(pieces) == 1 and pieces[0].exact and not self._by_extent
+                and arr.flags.writeable):
+            # the shard is the whole window and nothing is fed after it:
+            # adopt its bytes (read-only ones are copied, so the window
+            # stays writable)
+            self.out = arr
+            return
+        if self.out is None:
+            self.out = np.empty(window_shape(self.wanted), dtype=self.dtype)
         for piece in pieces:
             self.out[piece.dst] = arr[piece.src]
 

@@ -159,7 +159,8 @@ class Trainer:
                 restore_assemble_s=rm.assemble_seconds,
                 restore_h2d_s=rm.h2d_seconds,
                 restore_overlap_s=rm.overlap_seconds,
-                restore_peak_staged_bytes=rm.peak_staged_bytes)
+                restore_peak_staged_bytes=rm.peak_staged_bytes,
+                restore_direct_bytes=rm.direct_bytes)
         return state, start_step, restore_attr
 
     def _run_traced(self) -> dict:

@@ -58,6 +58,23 @@ def test_any_regrid_assembles_exactly(splits, wsplits):
                           window[1][0]:window[1][1]])
 
 
+@pytest.mark.parametrize("writable", [True, False])
+def test_exact_shard_becomes_the_window(writable):
+    """A shard whose index is the window becomes the window with no copy
+    when its bytes are writable; read-only bytes are copied, so the window
+    stays writable."""
+    rec, data, extents = _grid_record((16, 32), (2, 1))
+    window = ((0, 8), (0, 32))
+    (sh,) = [s for s in rec.shards if tuple(s.index) == window]
+    raw = extents[(sh.path, sh.offset)]
+    if not writable:
+        raw = np.frombuffer(raw.tobytes(), np.uint8)
+    out = assemble(rec, window, lambda s: raw)
+    np.testing.assert_array_equal(out, data[:8])
+    assert np.shares_memory(out, raw) == writable
+    assert out.flags.writeable
+
+
 def test_intersect():
     assert intersect(((0, 4),), ((2, 8),)) == ((2, 4),)
     assert intersect(((0, 4),), ((4, 8),)) is None

@@ -14,9 +14,14 @@ import pytest
 
 from repro.core import (CheckpointManager, ChecksumError, EngineConfig,
                         MultiLevelCheckpointer, make_cr_engine)
+from repro.core import pipeline as pipeline_mod
 from repro.core.aggregation import Strategy
+from repro.core.buffers import PAGE
+from repro.core.checkpoint import RestoreMetrics
 from repro.core.engines import ReadReq, SaveItem
+from repro.core.engines.aggregated import _AggReadStream
 from repro.core.manifest import Manifest, crc32_of
+from repro.core.pipeline import RestorePipeline, RestoreTask
 
 
 def _state(scale=1):
@@ -174,6 +179,10 @@ def test_restore_backpressure_caps_staged_bytes(tmp_path, rng):
     stats = stream.end_restore()
     assert 0 < stats.peak_staged_bytes <= budget
     assert stats.logical_bytes == sum(sizes)
+    # every extent here stands alone, so every read lands straight in the
+    # array get() returns: counted while in flight, the consumer's after
+    assert stats.direct_bytes == sum(sizes)
+    assert eng.pool.outstanding_bytes == 0
     eng.close()
 
 
@@ -379,3 +388,186 @@ def test_restore_abort_after_injected_crash_mid_stream(tmp_ckpt_dir):
         r = mgr.restore(state_template=state, step=1)   # retry, clean run
         np.testing.assert_array_equal(np.asarray(r["params"]["w"]),
                                       np.asarray(state["params"]["w"]))
+
+
+# ------------------------------------------------------------ direct landing
+def _record_windows(monkeypatch):
+    """The arrays the read stream hands out, and every WindowAssembler the
+    restore pipeline builds (by tensor key)."""
+    made, handed = {}, []
+
+    class Recording(pipeline_mod.WindowAssembler):
+        def __init__(self, record, wanted):
+            super().__init__(record, wanted)
+            made.setdefault(record.key, []).append(self)
+
+    get = _AggReadStream.get
+
+    def recording_get(stream, key):
+        arr = get(stream, key)
+        handed.append(arr)
+        return arr
+
+    monkeypatch.setattr(pipeline_mod, "WindowAssembler", Recording)
+    monkeypatch.setattr(_AggReadStream, "get", recording_get)
+    return made, handed
+
+
+@pytest.mark.parametrize("align", [PAGE, 2 * PAGE])
+@pytest.mark.parametrize("backend", ["posix", "threadpool"])
+def test_direct_landing_matches_monolithic(backend, align, tmp_path,
+                                           monkeypatch):
+    """A tensor that fills its window is read straight into page-aligned
+    memory that becomes the window array (a coalesced small tensor's copy
+    out of the pooled buffer does too): the leaf is the array the stream
+    handed out, and the bytes equal the monolithic restore's.
+    Byte counts that are not a multiple of ``align`` pad the landing buffer,
+    never the window."""
+    chunk = 1 << 20
+    n = 3 * chunk // 4 + 5              # 3 MiB + 20 B: four units, unaligned
+    state = {"a": np.arange(2 * n, dtype=np.float32)[::-1].copy(),
+             "b": np.full((7,), 0.5, np.float32),
+             "c": np.arange(300, dtype=np.int32),
+             "w": np.arange(n, dtype=np.float32)}
+    cfg = EngineConfig(backend=backend, chunk_bytes=chunk,
+                       coalesce_bytes=chunk, inflight_bytes=4 * chunk,
+                       align=align)
+    d = str(tmp_path / "ck")
+    made, handed = _record_windows(monkeypatch)
+    with CheckpointManager(d, config=cfg) as mgr:
+        mgr.save(1, state)
+        got = mgr.restore()
+        m = mgr.last_restore_metrics
+        # "b" and "c" share a coalesced read, copied out of a pooled buffer
+        assert m.direct_bytes == state["a"].nbytes + state["w"].nbytes
+        for k in state:                 # every window is what get() gave
+            (asm,) = made[k]
+            assert np.shares_memory(got[k], asm.out)
+            assert any(np.shares_memory(asm.out, h) for h in handed)
+        assert made["a"][0].out.ctypes.data % PAGE == 0
+        assert made["w"][0].out.ctypes.data % PAGE == 0
+        # out-of-order gets straight off the stream
+        man = Manifest.load(os.path.join(d, "step_00000001"))
+        reqs = [ReadReq(k, rec.shards[0].path, rec.shards[0].offset,
+                        rec.shards[0].nbytes)
+                for k, rec in man.tensors.items()]
+        stream = mgr.engine.begin_restore(os.path.join(d, "step_00000001"),
+                                          reqs)
+        for r in reversed(reqs):
+            assert stream.get(r.key).tobytes() == state[r.key].tobytes()
+        assert stream.end_restore().direct_bytes == m.direct_bytes
+        assert mgr.engine.pool.outstanding_bytes == 0
+    with CheckpointManager(d, config=cfg, streaming=False) as mgr:
+        mono = mgr.restore()
+    _assert_tree_equal(got, mono)
+    _assert_tree_equal(got, state)
+
+
+def _save_split_record(d, full):
+    """One record saved as two half shards, as two writers would."""
+    eng = make_cr_engine("aggregated", EngineConfig(checksum=True))
+    h = len(full) // 2
+    items = [SaveItem(f"w#{i}", full[lo:hi], "float32", full.shape,
+                      ((lo, hi),), record_key="w")
+             for i, (lo, hi) in enumerate([(0, h), (h, len(full))])]
+    man = eng.save(d, items)
+    return eng, man.tensors["w"]
+
+
+@pytest.mark.parametrize("case", ["shards_into_window", "shard_into_windows",
+                                  "quantized", "delta"])
+def test_direct_landing_declined_keeps_copy_path(case, tmp_path,
+                                                 monkeypatch):
+    """Where the bytes read are not the window one to one (several shards
+    per window, a shard split over windows, int8 moments to dequantize,
+    delta chunk references to join), the window is never an array the
+    stream handed out: a copy or a decode makes it, and the bytes equal
+    the monolithic restore's."""
+    full = np.arange(1 << 18, dtype=np.float32)
+    d = str(tmp_path / "ck")
+    made, handed = _record_windows(monkeypatch)
+
+    def assert_no_window_is_read_memory(n_windows):
+        windows = [a.out for asms in made.values() for a in asms]
+        assert len(windows) >= n_windows and handed
+        for win in windows:
+            assert not any(np.shares_memory(win, h) for h in handed)
+
+    if case == "shards_into_window":
+        eng, rec = _save_split_record(d, full)
+        m = RestoreMetrics(step=1)
+        task = RestoreTask("w", rec, [(((0, len(full)),), None)])
+        out = RestorePipeline(eng).run(d, [task], metrics=m)
+        np.testing.assert_array_equal(out["w"], full)
+        assert_no_window_is_read_memory(1)
+        eng.close()
+        return
+    state = {"w": full}
+    kw = {}
+    if case == "quantized":
+        state = {"mu": np.random.default_rng(1).standard_normal(
+            1 << 18).astype(np.float32)}
+        kw = dict(quantize_prefixes=("mu",))
+    elif case == "delta":
+        kw = dict(delta=True, delta_chunk_bytes=64 << 10)
+    window_fn = None
+    if case == "shard_into_windows":
+        h = len(full) // 2
+        window_fn = lambda rec: [(((0, h),), None), (((h, len(full)),), None)]
+    with CheckpointManager(d, **kw) as mgr:
+        mgr.save(1, state)
+        if case == "delta":
+            state = {"w": full.copy()}
+            state["w"][:7] = -1.0
+            mgr.save(2, state)
+        got = mgr.restore(window_fn=window_fn)
+        assert mgr.last_restore_metrics.mode == "streaming"
+        assert_no_window_is_read_memory(2 if window_fn else 1)
+    kw.pop("delta", None)               # the restore reads chunk refs anyway
+    with CheckpointManager(d, streaming=False, **kw) as mgr:
+        mono = mgr.restore(window_fn=window_fn)
+    _assert_tree_equal(got, mono)
+    if case == "shard_into_windows":
+        np.testing.assert_array_equal(got["w"], full[:len(full) // 2])
+    elif case == "delta":
+        _assert_tree_equal(got, state)
+
+
+@pytest.mark.parametrize("backend", ["posix", "threadpool"])
+def test_direct_landing_crc_catches_corrupt_chunk(backend, tmp_path, rng):
+    """A flipped byte inside a chunk read straight into the array ``get``
+    would return raises ChecksumError; the abort then settles the budget,
+    leaves no pooled buffer outstanding, and the engine restores cleanly
+    afterwards."""
+    chunk = 1 << 20
+    eng = make_cr_engine("aggregated", EngineConfig(
+        backend=backend, checksum=True, chunk_bytes=chunk,
+        coalesce_bytes=chunk, inflight_bytes=4 * chunk,
+        strategy=Strategy.FILE_PER_PROCESS))
+    d = str(tmp_path / "crc")
+    items, m = _save_items(eng, d, [4 * chunk + 100, 5000], rng, step=1)
+    sh = m.tensors["t0"].shards[0]
+    with open(os.path.join(d, sh.path), "r+b") as f:
+        f.seek(sh.offset + chunk + 12345)    # inside the second chunk
+        b = f.read(1)
+        f.seek(sh.offset + chunk + 12345)
+        f.write(bytes([b[0] ^ 0xFF]))
+
+    reqs = [ReadReq(k, rec.shards[0].path, rec.shards[0].offset,
+                    rec.shards[0].nbytes) for k, rec in m.tensors.items()]
+    crcs = {k: rec.shards[0].crc32 for k, rec in m.tensors.items()}
+    stream = eng.begin_restore(d, reqs, crcs=crcs)
+    with pytest.raises(ChecksumError, match="t0"):
+        stream.get("t0")
+    assert stream.stats.direct_bytes > chunk   # the bad chunk landed direct
+    stream.abort()
+    assert stream.budget.in_flight == 0
+    assert eng.pool.outstanding_bytes == 0
+    with open(os.path.join(d, sh.path), "r+b") as f:   # repair, read again
+        f.seek(sh.offset)
+        f.write(np.asarray(items[0].data).tobytes())
+    stream = eng.begin_restore(d, reqs, crcs=crcs)
+    for it in items:
+        assert stream.get(it.key).tobytes() == bytes(memoryview(it.data))
+    assert stream.end_restore().direct_bytes == 4 * chunk + 100 + 5000
+    eng.close()

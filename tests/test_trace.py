@@ -190,11 +190,13 @@ def _spans(events, name, root=None):
 
 def test_save_and_restore_name_their_host_work(tmp_path):
     """A streaming save's ``crc`` and ``stage.copy`` spans carry every byte
-    of its payload; a restore's ``read.land`` spans every byte it read, and
-    its ``read.wait``, ``read.land`` and ``crc`` spans lie inside the
-    ``restore`` span on the restore's own thread."""
+    of its payload; a restore's ``read.land`` spans every byte it read that
+    did not land straight in the array ``get`` returns (``read.direct_bytes``
+    counts the rest), and its ``read.wait``, ``read.land`` and ``crc`` spans
+    lie inside the ``restore`` span on the restore's own thread."""
     state = {"big": np.arange(1 << 20, dtype=np.float32),   # 4 chunks
-             "small": np.ones((8, 128), np.float32), "step": 3}
+             "small": np.ones((8, 128), np.float32),
+             "tiny": np.zeros((128,), np.float32), "step": 3}
     mgr = CheckpointManager(
         str(tmp_path / "ck"),
         config=EngineConfig(backend="threadpool", chunk_bytes=1 << 20,
@@ -204,6 +206,7 @@ def test_save_and_restore_name_their_host_work(tmp_path):
         sm = mgr.save(1, state)
         out = mgr.restore()
         rm = mgr.last_restore_metrics
+        counters = trace.active().counters()
         events = trace.drain()
     finally:
         trace.disable()
@@ -216,9 +219,13 @@ def test_save_and_restore_name_their_host_work(tmp_path):
     assert sum(e.nbytes for e in copies) == sm.total_bytes
     assert sum(e.nbytes for e in _spans(events, "crc", save)) == \
         sm.total_bytes
-    # the restore reads the lean blob and every tensor, and CRCs the tensors
-    assert sum(e.nbytes for e in _spans(events, "read.land", restore)) == \
-        sm.total_bytes
+    # the restore reads the lean blob and every tensor, and CRCs the tensors;
+    # an extent read alone ("big") lands in place with no copy; "small" and
+    # "tiny" share a coalesced read, copied out of its pooled buffer
+    assert rm.direct_bytes == state["big"].nbytes
+    assert counters["read.direct_bytes"] >= rm.direct_bytes
+    assert sum(e.nbytes for e in _spans(events, "read.land", restore)) + \
+        counters["read.direct_bytes"] == sm.total_bytes
     assert sum(e.nbytes for e in _spans(events, "crc", restore)) == \
         rm.total_bytes
     assert _spans(events, "read.wait")
