@@ -58,7 +58,7 @@ def main(argv=None) -> int:
     shutil.rmtree(ckpt_dir, ignore_errors=True)
     os.makedirs(ckpt_dir)
     run = loop.Run(cell=args.workload, config=config, traffic=traffic,
-                   seed=seeds[0], seconds=0)
+                   seed=seeds[0], seconds=0, chips=entry["chips"])
     cell = loop.Cell(run, ckpt_dir)
     ok = True
     try:
@@ -73,14 +73,15 @@ def main(argv=None) -> int:
             del state
             shape = cell.state_shape["params"]
             batches = cell.host_batches(3)
-            ref = reference.run_reference(config, seed, shape, batches)
+            on = {"devices": cell.devices}
+            ref = reference.run_reference(config, seed, shape, batches, **on)
             out = {"seed": seed, "losses": prog.losses,
                    "ref_losses": ref.losses,
                    "program": _gaps(prog, ref)}
             ctl = reference.run_reference(config, seed, shape, batches,
-                                          precision="fp8")
+                                          precision="fp8", **on)
             half = reference.run_reference(config, seed, shape, batches,
-                                           half_batch=True)
+                                           half_batch=True, **on)
             out["control"] = _gaps(ctl, ref)
             out["half_batch"] = _gaps(half, ref)
             out["seconds"] = time.perf_counter() - t0

@@ -13,6 +13,11 @@ Both build the Trainer's parts (its ``CheckpointManager`` through the
 Trainer, the jitted ``make_train_step`` with ``donate_argnums=(0,)``), make
 the state from the seed in one jitted call, and take the first three steps
 at set-up: those are compared with the plain reference after the window.
+
+Every cell runs on the mesh its configuration gives (``"mesh": {"data": d,
+"model": m}``, 1 x 1 when absent), laid out as the Trainer lays it out: the
+state's shardings are ``Partitioner.train_state_shardings``, the tokens'
+``Partitioner.tokens_sharding``.
 """
 
 from __future__ import annotations
@@ -70,6 +75,7 @@ class Run:
     traffic: dict
     seed: int
     seconds: float
+    chips: int = 1                    # the workload's, and the mesh's
     setup_s: float = 0.0
     window_s: float = 0.0
     steps: int = 0
@@ -85,10 +91,11 @@ class Run:
     spans: list = field(default_factory=list)          # program TraceEvents
     span_window: tuple = (0.0, 0.0)                    # trace.clock() bounds
     device_trace: object = None                        # devtrace.DeviceTrace
-    flops_per_step: float = 0.0
+    flops_per_step: float = 0.0                        # the global batch's
     peaks: dict = field(default_factory=dict)
     checks: check.Checks = field(default_factory=check.Checks)
     io: dict = field(default_factory=dict)
+    after_s: dict = field(default_factory=dict)   # work after the window
     trace_begin: object = None        # set by a traced run: see run.py
     trace_stop: object = None
     trace_deadline: float = math.inf
@@ -112,9 +119,11 @@ class Cell:
 
     def __init__(self, run: Run, ckpt_dir: str, step_factory=None):
         from repro.data import DataConfig
+        from repro.launch.mesh import make_host_mesh
         from repro.models.config import ModelConfig
         from repro.optim import AdamWConfig
-        from repro.train.steps import make_train_step
+        from repro.sharding.partition import Partitioner
+        from repro.train.steps import init_train_state, make_train_step
         from repro.train.trainer import Trainer, TrainerConfig
 
         cfg, tr = run.config, run.traffic
@@ -129,6 +138,13 @@ class Cell:
         self.seed = run.seed
         self.zipf_a = tr["zipf_a"]
         self.ckpt_dir = ckpt_dir
+        shape = cfg.get("mesh", {})
+        data, model = int(shape.get("data", 1)), int(shape.get("model", 1))
+        if data * model != run.chips:
+            raise ValueError(f"mesh data {data} x model {model} is not the "
+                             f"{run.chips} chips the cell asks for")
+        self.mesh = make_host_mesh(data, model)
+        self.devices = list(self.mesh.devices.flat)
         self.tcfg = TrainerConfig(
             steps=0, ckpt_every=max(int(tr.get("save_every_steps", 1)), 1),
             ckpt_dir=ckpt_dir, async_ckpt=True, keep=tr["keep"],
@@ -140,20 +156,23 @@ class Cell:
         self._Trainer = Trainer
         self.trainer = self.new_trainer()
         self.state_shape = jax.eval_shape(
-            lambda: self.trainer.init_state()[0])
-        self.device = jax.devices()[0]
-        self.sharding = jax.sharding.SingleDeviceSharding(self.device)
+            lambda: init_train_state(jax.random.key(0), self.model))
+        part = Partitioner(self.model, self.mesh)
+        self.shardings = part.train_state_shardings(self.state_shape)
+        self.tokens_sharding = part.tokens_sharding()
         self.template = jax.tree_util.tree_map(
-            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
-                                           sharding=self.sharding),
-            self.state_shape)
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            self.state_shape, self.shardings)
         self.key = S.weights_key(run.seed)
         factory = step_factory or make_train_step
+        # the state leaves the step in the layout it came in: the compiler
+        # would otherwise shard the new params over ``data`` as well
         self.step_fn = jax.jit(factory(self.model, self.opt),
-                               donate_argnums=(0,))
+                               donate_argnums=(0,),
+                               out_shardings=(self.shardings, None))
         shape = self.state_shape
         self.init_fn = jax.jit(lambda k: S.make_train_state(k, shape),
-                               out_shardings=self.sharding)
+                               out_shardings=self.shardings)
         self.fp_fn = jax.jit(S.fingerprint)
         b1 = self.opt.b1
         self.grad_fn = jax.jit(lambda mu: S.grad_norms_from_moment(mu, b1))
@@ -178,13 +197,14 @@ class Cell:
         return state, losses, grad_norms
 
     def new_trainer(self):
-        return self._Trainer(self.model, self.tcfg, opt_cfg=self.opt,
-                             data_cfg=self.data_cfg)
+        return self._Trainer(self.model, self.tcfg, mesh=self.mesh,
+                             opt_cfg=self.opt, data_cfg=self.data_cfg)
 
     def batch_at(self, step: int) -> dict:
         b = S.batch_at(self.seed, step, self.batch, self.seq_len,
                        self.model.vocab_size, self.zipf_a)
-        return {k: jax.device_put(v, self.sharding) for k, v in b.items()}
+        return {k: jax.device_put(v, self.tokens_sharding)
+                for k, v in b.items()}
 
     def host_batches(self, n: int) -> list[dict]:
         return [S.batch_at(self.seed, k, self.batch, self.seq_len,
@@ -233,9 +253,12 @@ def program_readings(losses, grad_norms, update_norms):
 
 def compare_with_reference(run: Run, cell: Cell, prog) -> None:
     """Run the plain reference over the same three steps; add the gaps."""
+    t0 = now()
     ref = reference.run_reference(run.config, run.seed,
                                   cell.state_shape["params"],
-                                  cell.host_batches(3))
+                                  cell.host_batches(3),
+                                  devices=cell.devices)
+    run.after_s["reference"] = now() - t0
     gaps = check.training_gaps(prog, ref)
     check.limit_checks(gaps, run.config["limits"], run.checks)
     run.io["gaps"] = {k: gaps[k] for k in check.NUMBERS}
@@ -292,8 +315,10 @@ def train_save(run: Run, cell: Cell, t_start: float, traced) -> None:
         run.window_s = now() - t0
         run.end_window()
         run.window_compiles = compiles.n - c0
+        t1 = now()
         tr.ckpt.wait()
-    run.memory_peak_bytes = peak_bytes(cell.device)
+        run.after_s["flush"] = now() - t1
+    run.memory_peak_bytes = peak_bytes(cell.devices)
     run.attempted = run.steps
     run.failed = sum(not math.isfinite(x) for x in window_losses)
     del state, m
@@ -305,10 +330,12 @@ def train_save(run: Run, cell: Cell, t_start: float, traced) -> None:
     run.checks.add("last_save_lost", int(bool(fps) and max(fps) not in
                                          committed), 0)
     if fps and max(fps) in committed:
+        t1 = now()
         want = np.asarray(fps[max(fps)])
         got = cell.restore_fingerprint(max(fps))
         run.checks.add("restored_leaves_differ",
                        int(np.sum(np.any(want != got, axis=1))), 0)
+        run.after_s["restore"] = now() - t1
     compare_with_reference(run, cell, prog)
 
 
@@ -348,6 +375,7 @@ def resume(run: Run, cell: Cell, t_start: float, traced) -> None:
     # the third checked step runs through a resume: it warms every program
     # the window's resumes use, and its loss is what each of them must give
     dt, start, fp, loss3, _, state = one_resume(cell)
+    run.io["warm_resume_s"] = dt
     losses.append(loss3)
     upd = cell.upd_fn(cell.key, state["params"])
     prog = program_readings(np.asarray(jax.device_get(losses)),
@@ -375,7 +403,7 @@ def resume(run: Run, cell: Cell, t_start: float, traced) -> None:
         run.window_s = now() - t0
         run.end_window()
         run.window_compiles = compiles.n - c0
-    run.memory_peak_bytes = peak_bytes(cell.device)
+    run.memory_peak_bytes = peak_bytes(cell.devices)
     gc.collect()
 
     run.attempted = len(results)
@@ -391,6 +419,7 @@ def resume(run: Run, cell: Cell, t_start: float, traced) -> None:
     compare_with_reference(run, cell, prog)
 
 
-def peak_bytes(device) -> int:
-    stats = device.memory_stats() or {}
-    return int(stats.get("peak_bytes_in_use", 0))
+def peak_bytes(devices) -> int:
+    """The fullest device's peak."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)

@@ -11,7 +11,9 @@ readings that the benchmark takes from the program:
   * per leaf, the norm of the params' change after three steps.
 
 The AdamW moments stay in host memory between steps, so the float32
-params and gradients alone sit on the device.
+params and gradients alone sit on the device. Those are spread over the
+devices the run was given by a rule of this module's own (``placement``),
+so that a state larger than one chip fits.
 """
 
 from __future__ import annotations
@@ -23,6 +25,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 from . import state as S
 from .refops import Precision, ce_sum
@@ -46,7 +49,24 @@ def load_model(name: str):
     return mod
 
 
-def _loss_and_grad(ref, model: dict, prec: Precision, rows_per_block: int):
+def placement(params_shape, devices) -> dict:
+    """Where each float32 leaf lives: split over every device on its
+    largest dimension that the device count divides, or whole on each."""
+    devices = list(devices)
+    mesh = Mesh(np.array(devices), ("d",), axis_types=(AxisType.Auto,))
+    n = len(devices)
+
+    def one(sds):
+        spec = [None] * len(sds.shape)
+        dims = [i for i, size in enumerate(sds.shape) if size % n == 0]
+        if dims:
+            spec[max(dims, key=lambda i: sds.shape[i])] = "d"
+        return NamedSharding(mesh, PartitionSpec(*spec))
+    return jax.tree_util.tree_map(one, params_shape)
+
+
+def _loss_and_grad(ref, model: dict, prec: Precision, rows_per_block: int,
+                   shardings):
     def block_loss(params, tokens, labels):
         h = ref.hidden(params, tokens, model, prec)
         return ce_sum(h, ref.out_weight(params, model), labels, prec)
@@ -70,7 +90,9 @@ def _loss_and_grad(ref, model: dict, prec: Precision, rows_per_block: int):
         n = tokens.size
         return lsum / n, jax.tree_util.tree_map(lambda g: g / n, gsum)
 
-    return jax.jit(total)
+    replicated = NamedSharding(
+        jax.tree_util.tree_leaves(shardings)[0].mesh, PartitionSpec())
+    return jax.jit(total, out_shardings=(replicated, shardings))
 
 
 @jax.jit
@@ -99,17 +121,23 @@ def lr_at(opt: dict, count: int) -> float:
 
 def run_reference(config: dict, seed: int, params_shape, batches: list[dict],
                   precision: str = "f32", half_batch: bool = False,
-                  ) -> Readings:
-    """The reference's readings over ``batches`` (one per step)."""
+                  devices=None) -> Readings:
+    """The reference's readings over ``batches`` (one per step), on
+    ``devices`` (the default device when None)."""
     ref = load_model(config["reference"])
     model, opt = config["model"], config["optimizer"]
     prec = Precision(precision)
+    shardings = placement(params_shape, devices or jax.devices()[:1])
     loss_grad = _loss_and_grad(ref, model, prec,
-                               config["train"]["reference_rows_per_block"])
+                               config["train"]["reference_rows_per_block"],
+                               shardings)
     key = S.weights_key(seed)
-    init = jax.jit(lambda k: S.make_params(k, params_shape, jnp.float32))
+    init = jax.jit(lambda k: S.make_params(k, params_shape, jnp.float32),
+                   out_shardings=shardings)
     params = init(key)
     leaves, tdef = jax.tree_util.tree_flatten(params)
+    places = jax.tree_util.tree_leaves(shardings)
+    whole = NamedSharding(places[0].mesh, PartitionSpec())
     mu = [None] * len(leaves)
     nu = [None] * len(leaves)
     losses, grad_norms = [], None
@@ -118,20 +146,23 @@ def run_reference(config: dict, seed: int, params_shape, batches: list[dict],
         if half_batch:
             tokens, labels = half_of(tokens), half_of(labels)
         loss, grads = loss_grad(jax.tree_util.tree_unflatten(tdef, leaves),
-                                jnp.asarray(tokens), jnp.asarray(labels))
+                                jax.device_put(tokens, whole),
+                                jax.device_put(labels, whole))
         losses.append(float(loss))
         g_leaves = jax.tree_util.tree_leaves(grads)
         gnorm = float(np.sqrt(sum(float(jnp.sum(jnp.square(g)))
                                   for g in g_leaves)))
         clip = opt["grad_clip"]
         scale = min(1.0, clip / (gnorm + 1e-9)) if clip else 1.0
-        if k == 1:
-            grad_norms = np.array([float(jnp.linalg.norm(g.reshape(-1)))
-                                   * scale for g in g_leaves])
+        if k == 1:   # no reshape: a leaf split over devices stays where it is
+            grad_norms = np.array([float(jnp.linalg.norm(g)) * scale
+                                   for g in g_leaves])
         new = []
         for i, (p, g) in enumerate(zip(leaves, g_leaves)):
-            m0 = jnp.zeros_like(p) if mu[i] is None else jnp.asarray(mu[i])
-            v0 = jnp.zeros_like(p) if nu[i] is None else jnp.asarray(nu[i])
+            m0 = (jnp.zeros_like(p) if mu[i] is None
+                  else jax.device_put(mu[i], places[i]))
+            v0 = (jnp.zeros_like(p) if nu[i] is None
+                  else jax.device_put(nu[i], places[i]))
             p, m1, v1 = _adamw_leaf(p, g, m0, v0, np.float32(scale),
                                     np.float32(k), np.float32(lr_at(opt, k)),
                                     np.float32(opt["b1"]),
@@ -140,11 +171,11 @@ def run_reference(config: dict, seed: int, params_shape, batches: list[dict],
                                     np.float32(opt["weight_decay"]))
             new.append(p)
             if k < len(batches):
-                mu[i], nu[i] = np.asarray(m1), np.asarray(v1)
+                mu[i], nu[i] = jax.device_get((m1, v1))
             del m0, v0, m1, v1
         del grads, g_leaves
         leaves = new
     p0 = jax.tree_util.tree_leaves(init(key))
-    upd = np.array([float(jnp.linalg.norm((a - b).reshape(-1)))
+    upd = np.array([float(jnp.linalg.norm(a - b))
                     for a, b in zip(leaves, p0)])
     return Readings(losses, grad_norms, upd)

@@ -92,16 +92,26 @@ def batch_at(seed: int, step: int, batch: int, seq_len: int, vocab: int,
 
 # ------------------------------------------------------ reads of the state
 def _words(x):
-    """Leaf bits as uint32 words."""
+    """Leaf bits as uint32 words, in the leaf's shape."""
     if x.dtype.itemsize == 4:
-        return jax.lax.bitcast_convert_type(x, jnp.uint32).reshape(-1)
+        return jax.lax.bitcast_convert_type(x, jnp.uint32)
     if x.dtype.itemsize == 2:
-        return jax.lax.bitcast_convert_type(x, jnp.uint16) \
-            .reshape(-1).astype(jnp.uint32)
+        return jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
     if x.dtype.itemsize == 1:
-        return jax.lax.bitcast_convert_type(x, jnp.uint8) \
-            .reshape(-1).astype(jnp.uint32)
+        return jax.lax.bitcast_convert_type(x, jnp.uint8).astype(jnp.uint32)
     raise TypeError(f"no fingerprint for {x.dtype}")
+
+
+def _weights(shape):
+    """2 * (row-major position) + 1 of every element, mod 2**32, built
+    without flattening, so that a sharded leaf is read where it lies."""
+    pos = jnp.zeros(shape, jnp.uint32)
+    stride = 1
+    for axis in reversed(range(len(shape))):
+        pos = pos + jax.lax.broadcasted_iota(jnp.uint32, shape, axis) \
+            * jnp.uint32(stride % (1 << 32))
+        stride *= shape[axis]
+    return pos * jnp.uint32(2) + jnp.uint32(1)
 
 
 def fingerprint(tree):
@@ -110,10 +120,9 @@ def fingerprint(tree):
     out = []
     for x in jax.tree_util.tree_leaves(tree):
         w = _words(x)
-        pos = jnp.arange(w.shape[0], dtype=jnp.uint32) * jnp.uint32(2) \
-            + jnp.uint32(1)
         out.append(jnp.stack([jnp.sum(w, dtype=jnp.uint32),
-                              jnp.sum(w * pos, dtype=jnp.uint32)]))
+                              jnp.sum(w * _weights(w.shape),
+                                      dtype=jnp.uint32)]))
     return jnp.stack(out)
 
 

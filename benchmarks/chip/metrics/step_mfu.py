@@ -1,6 +1,7 @@
-"""Model FLOPs of one training step (``chipbench.flops``, no recompute)
-over the mean device time of the train-step program in the profiler trace
-times the chip's bf16 peak, in percent."""
+"""Model FLOPs of one training step of the global batch
+(``chipbench.flops``, no recompute) over the mean device time of the
+train-step program in the profiler trace (each device's run of it counts
+once) times the bf16 peak of every chip in the cell's mesh, in percent."""
 
 PROGRAM = "jit_train_step"
 
@@ -11,4 +12,5 @@ def read(run):
     if not times:
         return None
     mean = sum(times) / len(times)
-    return 100.0 * run.flops_per_step / (mean * run.peaks["bf16_flops"])
+    peak = run.peaks["bf16_flops"] * run.chips
+    return 100.0 * run.flops_per_step / (mean * peak)

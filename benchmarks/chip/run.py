@@ -160,7 +160,7 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: int,
     log("io: " + json.dumps(io), flush=True)
 
     run = loop.Run(cell=name, config=config, traffic=traffic, seed=seed,
-                   seconds=seconds, peaks=peaks, io=io)
+                   seconds=seconds, chips=entry["chips"], peaks=peaks, io=io)
     t = config["train"]
     run.flops_per_step = flops.train_flops_per_step(
         config["model"], t["batch"], t["seq_len"])
@@ -194,8 +194,14 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: int,
     log("run: " + json.dumps({
         "steps": run.steps, "saves": len(run.saves),
         "resumes": len(run.resume_s), "window_s": run.window_s,
+        "each_resume_s": run.resume_s,
+        "each_read_s": [getattr(rm, "read_seconds", None)
+                        for rm in run.restores],
+        "warm_resume_s": run.io.get("warm_resume_s"),
         "window_compiles": run.window_compiles,
         "stall_s": run.stall_s, "flops_per_step": run.flops_per_step,
+        "after_window_s": run.after_s,
+        "save_bytes": [[sm.total_bytes, sm.written_bytes] for sm in run.saves],
         "save_spans": _save_spans(run),
         "leaves_left_out": run.io.get("leaves_left_out"),
         "worst_leaves": run.io.get("worst_leaves"),

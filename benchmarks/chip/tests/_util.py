@@ -35,8 +35,12 @@ def load_run_module():
 
 
 def cell_files(name: str):
-    """(entry, config, traffic) of cell ``<config>.<traffic>`` from its
-    files, whether or not BENCHMARK.json lists it yet."""
+    """(entry, config, traffic) of cell ``name``: as BENCHMARK.json lists
+    it, or, for a cell it does not list yet, of ``<config>.<traffic>``
+    from its files."""
+    bench = spec.load_benchmark()
+    if any(w["name"] == name for w in bench["workloads"]):
+        return spec.load_cell(bench, name)
     cfg, traffic_name = name.split(".", 1)
     with open(os.path.join(CHIP, "configs", f"{cfg}.json")) as f:
         config = json.load(f)
@@ -57,7 +61,9 @@ def small_files(name: str, *, save_every: int = 2):
     m, t = config["model"], config["train"]
     m.update(num_layers=2, d_model=64, num_heads=4, num_kv_heads=4,
              d_ff=96, vocab_size=512)
-    t.update(batch=1, seq_len=1024, reference_rows_per_block=1)
+    # a row for each data replica of the cell's mesh
+    t.update(batch=config.get("mesh", {}).get("data", 1), seq_len=1024,
+             reference_rows_per_block=1)
     m["dtype"] = "float32"
     config["limits"] = dict(SMALL_LIMITS)
     traffic = dict(traffic)

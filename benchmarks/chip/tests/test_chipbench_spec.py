@@ -57,7 +57,9 @@ def test_bounds():
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
 def test_cell_loads_by_name(cell):
     entry, config, traffic = spec.load_cell(BENCH, cell)
-    assert entry["chips"] == 1
+    mesh = config.get("mesh", {})
+    assert entry["chips"] in (1, 4)
+    assert mesh.get("data", 1) * mesh.get("model", 1) == entry["chips"]
     assert traffic["loop"] in ("train_save", "resume")
     from chipbench.check import NUMBERS
     assert config["limits"] and set(config["limits"]) <= set(NUMBERS)
